@@ -22,6 +22,9 @@ if _SRC not in sys.path:
 
 
 def main(argv=None) -> None:
+    from repro import compile_cache
+
+    compile_cache.enable()
     import inspect
 
     ap = argparse.ArgumentParser(description=__doc__)

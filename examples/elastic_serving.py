@@ -7,12 +7,14 @@ scale with zero KV-cache movement (vs the SN baseline that ships slots).
 import numpy as np
 import jax
 
+from repro import compile_cache
 from repro.configs import get_config, reduced
 from repro.models import transformer
 from repro.serving.kv_pool import Request, ServingEngine
 
 
 def main():
+    compile_cache.enable()
     cfg = reduced(get_config("qwen3_14b"))
     params = transformer.init_params(jax.random.PRNGKey(0), cfg)
     eng = ServingEngine(cfg, params, n_slots=6, max_seq=48, n_instances=4)

@@ -25,6 +25,7 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
 import numpy as np
 import jax
 
+from repro import compile_cache
 from repro.core.aggregate import longest_aggregate
 from repro.core.controller import Reconfiguration, active_mask, balanced_fmu
 from repro.core.runtime import MeshPipeline
@@ -45,6 +46,7 @@ def tweets(rng, t0, n):
 
 
 def main():
+    compile_cache.enable()
     op = longest_aggregate(WindowSpec(wa=30 * MIN, ws=60 * MIN, wt="multi"),
                            k_virt=K, out_cap=256)
     mesh = make_stream_mesh(N_DEV)

@@ -10,6 +10,7 @@ mid-stream with a <40 ms, zero-state-transfer reconfiguration.
 import numpy as np
 import jax
 
+from repro import compile_cache
 from repro.core.aggregate import longest_aggregate
 from repro.core.controller import Reconfiguration, active_mask, balanced_fmu
 from repro.core.runtime import VSNPipeline
@@ -30,6 +31,7 @@ def tweets(rng, t0, n):
 
 
 def main():
+    compile_cache.enable()
     op = longest_aggregate(WindowSpec(wa=30 * MIN, ws=60 * MIN, wt="multi"),
                            k_virt=K, out_cap=256)
     pipe = VSNPipeline(op, n_max=4, n_active=2, stash_cap=64)

@@ -10,6 +10,7 @@ the deliverable is:
 import argparse
 import dataclasses
 
+from repro import compile_cache
 from repro.configs import get_config, reduced
 from repro.launch import train as T
 from repro.models.config import ModelConfig
@@ -23,6 +24,7 @@ def config_100m() -> ModelConfig:
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="~100M params (default: reduced smoke config)")

@@ -110,8 +110,8 @@ def fast_init(op: OperatorDef) -> FastAggState:
 def _segment_tile_k(k: int) -> int:
     """Largest MXU-friendly tile that divides K (the kernel asserts K % tile).
 
-    The hit-block axis needs no shim here: ``segment_aggregate`` lane-pads
-    N to a multiple of 128 internally (dead -1 keys, zero values), so the
+    The hit-block axis needs no shim here: ``segment_aggregate`` pads N
+    to whole hit blocks internally (dead -1 keys, zero values), so the
     concatenated (slot-generation x key-column) hit vectors below can have
     any length on any backend."""
     return 128 if k % 128 == 0 else k

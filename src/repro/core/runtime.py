@@ -754,12 +754,16 @@ class MeshPipeline:
     def collective_bytes(self):
         """Cross-device traffic of the compiled step(s), from the HLO: the
         zero-state-transfer witness (Theorem 3).  Returns {collective-kind:
-        bytes} summed over every step variant compiled so far."""
+        bytes} summed over every step variant compiled so far, batched
+        (``run``) and persistent (``run_persistent_staged``) alike."""
         from repro.launch.mesh import collective_bytes as _cb
 
+        texts = [self._jit.lower(*structs).compile().as_text()
+                 for structs in self._arg_structs.values()]
+        if self._persistent_structs:
+            texts.append(self.persistent_hlo())
         total = {}
-        for structs in self._arg_structs.values():
-            hlo = self._jit.lower(*structs).compile().as_text()
+        for hlo in texts:
             for kind, b in _cb(hlo).items():
                 total[kind] = total.get(kind, 0) + b
         return total

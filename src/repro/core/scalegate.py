@@ -101,24 +101,20 @@ def merge_order(tau: jax.Array, source: jax.Array, valid: jax.Array,
                 n_sources: int, backend: str = None) -> jax.Array:
     """The merge's total order, via the kernel backend dispatcher.
 
-    ``xla`` (the CPU default) keeps the exact legacy order — lexicographic
-    ``(tau, source, arrival)``.  The Pallas backends run the
-    ``scalegate_merge`` bitonic network, which orders by ``(tau, arrival)``;
-    both are valid ScaleGate total orders (see ``TIE_BREAK`` above).  The
-    kernel itself now pads any batch to a power-of-two (rows, 128) tile
-    internally (the Mosaic-ready 2-D layout), but non-power-of-two batches
-    still take the argsort path here so their tie-break stays pinned to
-    the documented xla contract.
+    ``xla`` (the CPU default) keeps the lexicographic
+    ``(tau, source, arrival)`` argsort.  The Pallas backends always run the
+    ``scalegate_merge`` bitonic network, which orders by ``(tau, arrival)``
+    and pads any batch length to a power-of-two (rows, 128) tile itself;
+    both are valid ScaleGate total orders (see ``TIE_BREAK`` above).
     """
     from repro.kernels import dispatch
 
-    n = tau.shape[0]
-    if dispatch.resolve(backend) != "xla" and n > 1 and n & (n - 1) == 0:
-        from repro.kernels.scalegate_merge.ops import scalegate_merge_op
-        order, _, _ = scalegate_merge_op(tau, source, valid,
-                                         n_sources=n_sources, backend=backend)
-        return order
-    return _stable_order(tau, source, valid)
+    if dispatch.resolve(backend) == "xla":
+        return _stable_order(tau, source, valid)
+    from repro.kernels.scalegate_merge.ops import scalegate_merge_op
+    order, _, _ = scalegate_merge_op(tau, source, valid,
+                                     n_sources=n_sources, backend=backend)
+    return order
 
 
 def push(state: ScaleGateState, incoming: T.TupleBatch, *,

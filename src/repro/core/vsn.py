@@ -316,8 +316,6 @@ def shard_tick(mesh, axis: str, k_virt: int, make_local_tick: Callable,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     n_shards = mesh.shape[axis]
     assert k_virt % n_shards == 0, (k_virt, n_shards)
     rows = k_virt // n_shards
@@ -335,7 +333,7 @@ def shard_tick(mesh, axis: str, k_virt: int, make_local_tick: Callable,
         return sigma, _lift_outs(outs)
 
     def step(sigma, ready_stack):
-        return shard_map(body, mesh=mesh,
+        return jax.shard_map(body, mesh=mesh,
                          in_specs=(spec_sigma, P()),
                          out_specs=(spec_sigma, _outs_spec(axis)),
                          check_vma=False)(sigma, ready_stack)
@@ -364,8 +362,6 @@ def shard_pipeline_step(op: OperatorDef, mesh, axis: str,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     n_shards = mesh.shape[axis]
     assert op.k_virt % n_shards == 0, (op.k_virt, n_shards)
     rows = op.k_virt // n_shards
@@ -387,7 +383,7 @@ def shard_pipeline_step(op: OperatorDef, mesh, axis: str,
         return sg, epoch, sigma, _lift_outs(o1), _lift_outs(o2), sw, wmk
 
     def step(sg, epoch, sigma, inc_stack, fmu_new, active_new):
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(), P(), spec_sigma, P(), P(), P()),
             out_specs=(P(), P(), spec_sigma, _outs_spec(axis),

@@ -273,13 +273,18 @@ def run_gate_loop(gate: LeafGate, recv, send, ship_obs: bool = False) -> None:
 
 
 def process_worker_main(cfg: Dict, in_q, out_q) -> None:
-    """Child-process entry point (spawn context: top-level importable).
-
-    ``cfg`` carries the LeafGate constructor args as picklable values; jax
-    initializes fresh in the child (CPU), and all channel payloads are
-    numpy.  Mirrors ``run_gate_loop`` over the mp queues.
+    """Child-process body, started through ``repro.host_worker.run``, which
+    has already pinned this process's JAX to the CPU and its kernel backend
+    to ``xla`` (an ingest host is a CPU host; the chip belongs to the
+    parent).  ``cfg`` carries the LeafGate constructor args as picklable
+    values, and all channel payloads are numpy.  Mirrors ``run_gate_loop``
+    over the mp queues; the first event it records, ``leaf_start``, names
+    the platform and backend the leaf runs on.
     """
+    import jax
+
     from repro import obs as _obs
+    from repro.kernels import dispatch
     from repro.ingest.channels import MP_CLOSE
     from repro.io.queues import QueueClosed
 
@@ -292,8 +297,11 @@ def process_worker_main(cfg: Dict, in_q, out_q) -> None:
 
     gate = LeafGate(cfg["leaf_id"], cfg["n_sources"],
                     np.asarray(cfg["owned"], bool), cfg["cap"], cfg["kmax"],
-                    cfg["payload_width"], backend=cfg.get("backend"),
-                    chunk=cfg.get("chunk"), state=cfg.get("state"))
+                    cfg["payload_width"], chunk=cfg.get("chunk"),
+                    state=cfg.get("state"))
+    _obs.event("leaf_start", leaf_id=gate.leaf_id,
+               platform=jax.devices()[0].platform,
+               backend=dispatch.resolve(gate.backend))
 
     def recv():
         msg = in_q.get()

@@ -119,6 +119,7 @@ class RootMerge:
         self.rounds = 0
         self._out_valid: List = []       # device count handles, unsynced
         self._last_overflow_warned = 0
+        self._stacked_structs = None     # abstract args of the last round
 
     @property
     def overflow(self) -> int:
@@ -243,6 +244,7 @@ class RootMerge:
         the stacked buffer preserves the leaves' relative lane order, so
         the emitted (tau, arrival) stream groups exactly like the host
         path's compacted concat."""
+        import jax
         import jax.numpy as jnp
 
         reports, rmask = self._fold_leaf_reports(outs)
@@ -266,15 +268,24 @@ class RootMerge:
         stacked = T.TupleBatch(
             **{f: jnp.asarray(np.stack([r[f] for r in rows]))
                for f in FIELDS})
-        self.state, out = self._push_stacked(
-            self.state, stacked, jnp.asarray(reports, jnp.int32),
-            jnp.asarray(rmask))
+        args = (self.state, stacked, jnp.asarray(reports, jnp.int32),
+                jnp.asarray(rmask))
+        self._stacked_structs = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
+        self.state, out = self._push_stacked(*args)
         self.rounds += 1
         _obs.counter_inc("root.rounds")
         self._out_valid.append(out.num_valid())
         if self.check_every and self.rounds % self.check_every == 0:
             self._verify_round(out)
         return out
+
+    def stacked_hlo(self) -> str:
+        """Compiled HLO of the fused round at the last shape pushed — the
+        ``scalegate_merge_stacked`` kernel shows in it as a TPU custom
+        call on the chip."""
+        return self._push_stacked.lower(
+            *self._stacked_structs).compile().as_text()
 
     def _verify_round(self, out: T.TupleBatch) -> None:
         """The host-path invariant checks, run periodically on the device

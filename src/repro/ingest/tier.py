@@ -46,6 +46,7 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import host_worker
 from repro import obs as _obs
 from repro.core import scalegate
 from repro.core import tuples as T
@@ -317,10 +318,12 @@ class IngestTier:
                 args=(gate, h.chan.get, self._root_in.put), daemon=True)
             h.thread.start()
         else:                                     # process
+            # no backend: a leaf process runs on the host CPU (xla), never
+            # on the chip the parent holds (repro.host_worker)
             cfg = dict(leaf_id=leaf_id, n_sources=self.n_sources,
                        owned=np.asarray(owned, bool), cap=self.leaf_cap,
                        kmax=self._kmax, payload_width=self._pw,
-                       backend=self.backend, state=state)
+                       state=state)
             o = _obs.get()
             if o is not None:
                 # the child installs its own Obs with the parent's config
@@ -328,8 +331,9 @@ class IngestTier:
                 cfg["obs"] = o.cfg.to_dict()
             h.chan = make_channel("process", self.chan_cap, self._ctx)
             h.proc = self._ctx.Process(
-                target=L.process_worker_main,
-                args=(cfg, h.chan._q, self._root_in._q), daemon=True)
+                target=host_worker.run,
+                args=("repro.ingest.leaf:process_worker_main", cfg,
+                      h.chan._q, self._root_in._q), daemon=True)
             h.proc.start()
         self._handles[leaf_id] = h
 

@@ -20,9 +20,10 @@ def flatten_outputs(outs) -> List[Tuple[int, tuple]]:
     tau = np.asarray(outs.tau).reshape(-1)
     val = np.asarray(outs.valid).reshape(-1)
     pay = np.asarray(outs.payload)
-    pay = pay.reshape(-1, pay.shape[-1])
-    return [(int(t), tuple(np.round(p, 4)))
-            for t, p, ok in zip(tau, pay, val) if ok]
+    # round in float64: numpy rounds a float32 by scaling it by 10**4 in
+    # float32, which moves any value above ~1678 (26869 -> 26868.998)
+    pay = np.round(pay.reshape(-1, pay.shape[-1])[val].astype(np.float64), 4)
+    return [(int(t), tuple(p)) for t, p in zip(tau[val], pay)]
 
 
 class CollectSink:

@@ -53,10 +53,9 @@ def _check_backend(backend: str) -> str:
 
 @functools.lru_cache(maxsize=1)
 def _has_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # no try/except: a TPU that fails to initialize must surface, not
+    # quietly turn every kernel into its CPU oracle
+    return jax.devices()[0].platform == "tpu"
 
 
 def default_backend() -> str:

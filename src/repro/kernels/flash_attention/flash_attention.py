@@ -26,8 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowering import tpu_compiler_params
-
 NEG_INF = -1e30
 
 
@@ -83,7 +81,7 @@ def _kernel(scale, causal, window, blk_q, blk_k, seq_kv,
 def pallas_specs(bh: int, sq: int, skv: int, d: int, blk_q: int, blk_k: int,
                  dtype=jnp.float32):
     """Grid/Block/out structure, shared with the lowering lint."""
-    specs = dict(
+    return dict(
         grid=(bh, sq // blk_q, skv // blk_k),
         in_specs=[
             pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
@@ -97,12 +95,9 @@ def pallas_specs(bh: int, sq: int, skv: int, d: int, blk_q: int, blk_k: int,
             pltpu.VMEM((blk_q, 1), jnp.float32),   # running denom
             pltpu.VMEM((blk_q, d), jnp.float32),   # running accumulator
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
     )
-    params = tpu_compiler_params(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-    if params is not None:
-        specs["compiler_params"] = params
-    return specs
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
@@ -121,4 +116,5 @@ def flash_attention(q, k, v, *, causal: bool = True, window=None,
         kern,
         **pallas_specs(bh, sq, skv, d, blk_q, blk_k, q.dtype),
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -18,8 +18,8 @@ what the VPU parallelizes (vectorized over Dk x Dv).  A matmul
 (intra-chunk-attention) formulation is a further MXU optimization recorded
 in EXPERIMENTS.md §Perf.
 
-Mosaic-ready by construction (ISSUE 5): rank-3 BlockSpecs/out_shape, no
-iota at all (time stepping is ``dynamic_slice``), rank-1-free dot_generals
+Mosaic-ready by construction: rank-3 BlockSpecs/out_shape, no iota at all
+(time stepping indexes the refs with ``pl.ds``), rank-1-free dot_generals
 with explicit ``preferred_element_type``, and grid dimension semantics
 (BH parallel, the chunk axis ``arbitrary`` — the carried state scratch
 makes it sequential).
@@ -34,8 +34,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.lowering import tpu_compiler_params
-
 
 def _kernel(chunk, use_bonus, r_ref, k_ref, v_ref, w_ref, u_ref, o_ref,
             state_ref):
@@ -45,18 +43,16 @@ def _kernel(chunk, use_bonus, r_ref, k_ref, v_ref, w_ref, u_ref, o_ref,
     def _init():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    r = r_ref[0]        # [C, Dk]
-    k = k_ref[0]        # [C, Dk]
-    v = v_ref[0]        # [C, Dv]
-    w = w_ref[0]        # [C, Dk] decay in (0, 1)
     u = u_ref[0]        # [1, Dk] bonus (rwkv6) — zeros for plain SSM
 
-    def step(t, carry):
-        s, out = carry
-        kt = jax.lax.dynamic_slice_in_dim(k, t, 1, 0)       # [1, Dk]
-        vt = jax.lax.dynamic_slice_in_dim(v, t, 1, 0)       # [1, Dv]
-        rt = jax.lax.dynamic_slice_in_dim(r, t, 1, 0)       # [1, Dk]
-        wt = jax.lax.dynamic_slice_in_dim(w, t, 1, 0)       # [1, Dk]
+    def step(t, s):
+        # time stepping slices the refs (``pl.ds``): Mosaic lowers a
+        # dynamic ref index, not ``dynamic_slice`` on loaded values
+        row = (0, pl.ds(t, 1), slice(None))
+        kt = k_ref[row]                                     # [1, Dk]
+        vt = v_ref[row]                                     # [1, Dv]
+        rt = r_ref[row]                                     # [1, Dk]
+        wt = w_ref[row]                                     # [1, Dk]
         # outer product k_t^T v_t on the MXU: contract the length-1 time dim
         kv = jax.lax.dot_general(kt, vt, (((0,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [Dk, Dv]
@@ -66,22 +62,16 @@ def _kernel(chunk, use_bonus, r_ref, k_ref, v_ref, w_ref, u_ref, o_ref,
             att = s
         ot = jax.lax.dot_general(rt, att, (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [1, Dv]
-        s = wt.T * s + kv
-        out = jax.lax.dynamic_update_slice_in_dim(out, ot.astype(out.dtype),
-                                                  t, 0)
-        return s, out
+        o_ref[row] = ot.astype(o_ref.dtype)
+        return wt.T * s + kv
 
-    s0 = state_ref[...]
-    out0 = jnp.zeros_like(o_ref[0])
-    s, out = jax.lax.fori_loop(0, chunk, step, (s0, out0))
-    state_ref[...] = s
-    o_ref[0] = out
+    state_ref[...] = jax.lax.fori_loop(0, chunk, step, state_ref[...])
 
 
 def pallas_specs(bh: int, t: int, dk: int, dv: int, chunk: int,
                  dtype=jnp.float32):
     """Grid/Block/out structure, shared with the lowering lint."""
-    specs = dict(
+    return dict(
         grid=(bh, t // chunk),
         in_specs=[
             pl.BlockSpec((1, chunk, dk), lambda b, c: (b, c, 0)),
@@ -93,12 +83,9 @@ def pallas_specs(bh: int, t: int, dk: int, dv: int, chunk: int,
         out_specs=pl.BlockSpec((1, chunk, dv), lambda b, c: (b, c, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, t, dv), dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )
-    params = tpu_compiler_params(
-        dimension_semantics=("parallel", "arbitrary"))
-    if params is not None:
-        specs["compiler_params"] = params
-    return specs
 
 
 def linear_scan(r, k, v, w, u=None, *, chunk: int = 64,
@@ -118,4 +105,5 @@ def linear_scan(r, k, v, w, u=None, *, chunk: int = 64,
         kern,
         **pallas_specs(bh, t, dk, dv, chunk, r.dtype),
         interpret=interpret,
+        name="linear_scan",
     )(r, k, v, w, u)

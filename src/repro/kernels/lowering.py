@@ -1,4 +1,4 @@
-"""Mosaic-lowering readiness: structural lint + best-effort AOT smoke.
+"""Mosaic-lowering readiness: a structural lint that needs no TPU.
 
 The Pallas interpreter (and the jnp ``xla`` oracles) will happily execute
 kernel shapes the Mosaic TPU compiler rejects — rank-1 BlockSpecs and 1-D
@@ -11,9 +11,10 @@ interpreter hides").  This module makes that class of regression
   walks the traced kernel jaxpr inside each ``pallas_call`` equation for
   rank-1 ``iota`` — the primitive both ``jnp.arange`` and 1-D
   ``jax.lax.iota`` lower to.  Pure tracing: runs on any host, no TPU.
-* ``lowering_smoke`` additionally runs ``jax.jit(...).lower()`` — the full
-  Mosaic pipeline — when a TPU backend is actually present (CI keeps a
-  ``REPRO_TPU=1`` job stub ready for hardware bring-up).
+
+``tests/test_chip_compile.py`` goes further: it compiles every registered
+kernel for a described (not attached) TPU v5e, which runs the full Mosaic
+pipeline the chip runs.
 
 Each ``kernels/*/ops.py`` registers a ``KernelCase`` factory with
 ``dispatch.register_lint``; the kernel modules expose their exact
@@ -25,25 +26,9 @@ registered kernel as a tier-1 regression gate.
 from __future__ import annotations
 
 import dataclasses
-import os
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Sequence
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-
-def tpu_compiler_params(*, dimension_semantics: Optional[Tuple[str, ...]] = None):
-    """Best-effort ``TPUCompilerParams`` across jax versions (renamed to
-    ``CompilerParams`` upstream); ``None`` when the running jax has
-    neither — callers then simply omit ``compiler_params``."""
-    cls = (getattr(pltpu, "CompilerParams", None)
-           or getattr(pltpu, "TPUCompilerParams", None))
-    if cls is None:
-        return None
-    try:
-        return cls(dimension_semantics=dimension_semantics)
-    except TypeError:                        # pragma: no cover - old signature
-        return None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,25 +150,3 @@ def lint_registered() -> Dict[str, LintReport]:
     for name, case_fn in sorted(dispatch.lint_cases().items()):
         reports[name] = lint_case(case_fn())
     return reports
-
-
-def tpu_present() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:                        # pragma: no cover
-        return False
-
-
-def smoke_requested() -> bool:
-    """The real-hardware gate: CI sets REPRO_TPU=1 on the TPU runner."""
-    return os.environ.get("REPRO_TPU") == "1"
-
-
-def lowering_smoke(case: KernelCase) -> Optional[str]:
-    """Best-effort AOT ``jit(...).lower()`` through the full Mosaic
-    pipeline.  Returns ``None`` on success, a skip reason when no TPU
-    backend is attached, and raises on a genuine lowering failure."""
-    if not tpu_present():
-        return "no TPU backend attached (structural lint still ran)"
-    jax.jit(case.fn).lower(*case.args)       # raises on Mosaic rejection
-    return None

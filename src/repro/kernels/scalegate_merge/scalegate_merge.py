@@ -22,8 +22,8 @@ exactly what the Mosaic lowering path needs (``pltpu.roll`` is the native
 lane rotation).  The carried triple is ``(key, lane, valid)`` so readiness
 never gathers back through the permutation.
 
-Single-program kernel (ticks are small: <= 4K lanes), entire tick resident
-in VMEM.  ``scalegate_merge`` pads any batch to the next power of two of
+Single-program kernel, entire tick resident in VMEM (a 32K-lane tick is
+128 KiB per carried array).  ``scalegate_merge`` pads any batch to the next power of two of
 at least 128 lanes; padding lanes carry ``(INF_TIME, lane >= n)`` keys, so
 they sort strictly after every real lane and the first ``n`` sorted
 positions are exactly the unpadded order.
@@ -41,29 +41,30 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.watermark import INF_TIME
 
 LANES = 128                     # TPU vector lane width (last-dim tile)
+# The watermark leaves the kernel as one full (8, 128) int32 tile (every
+# lane holds W): Mosaic stores vectors, not scalars, to VMEM, and a full
+# tile satisfies the block-shape rule with no SMEM round trip.
+WMARK_TILE = (8, LANES)
 
 
 def _roll(x, shift, axis):
     """Circular shift; ``pltpu.roll`` is the Mosaic-native lane rotation
-    (its shift must be non-negative, so normalize mod the axis size)."""
+    (its shift must be non-negative, so normalize mod the axis size).
+    ``shift`` may be traced: the network's strides are loop variables."""
     return pltpu.roll(x, shift % x.shape[axis], axis)
 
 
-def _cmp_exchange(key, idx, val, stride, asc):
+def _cmp_exchange(carry, sh, axis, coord, asc):
     """One bitonic compare-exchange pass over the row-major (R, 128) tile.
 
-    ``stride`` pairs flat lane ``p`` with ``p ^ stride``; ``asc`` is the
+    The pass pairs flat lane ``p`` with ``p ^ stride``: ``axis=1`` strides
+    ``sh`` lanes (stride < 128), ``axis=0`` strides ``sh`` rows (stride =
+    128 * sh); ``coord`` is the iota along ``axis`` and ``asc`` the
     per-lane ascending-block mask of the enclosing stage.  The pass is two
     rolls + selects per carried array: lanes with the stride bit clear
     read their partner ``stride`` ahead, the others ``stride`` behind.
     """
-    r, c = key.shape
-    if stride >= c:
-        axis, sh = 0, stride // c
-        coord = jax.lax.broadcasted_iota(jnp.int32, (r, c), 0)
-    else:
-        axis, sh = 1, stride
-        coord = jax.lax.broadcasted_iota(jnp.int32, (r, c), 1)
+    key, idx, val = carry
     is_lo = (coord & sh) == 0
 
     def partner(x):
@@ -74,8 +75,9 @@ def _cmp_exchange(key, idx, val, stride, asc):
     lex_gt = (key > pk) | ((key == pk) & (idx > pi))
     # In an ascending block the lo lane keeps the smaller element (and the
     # hi lane the larger); descending blocks mirror.  ``take`` selects the
-    # partner's element exactly when ours is on the wrong side.
-    take = jnp.where(asc == is_lo, lex_gt, ~lex_gt)
+    # partner's element exactly when ours is on the wrong side.  (An XOR,
+    # not a select between two bool vectors, which Mosaic cannot lower.)
+    take = lex_gt ^ (asc != is_lo)
     return (jnp.where(take, pk, key), jnp.where(take, pi, idx),
             jnp.where(take, pv, val))
 
@@ -85,22 +87,39 @@ def _sort_ready(tau, valid, w):
     and gate readiness against the scalar watermark ``w`` without a gather
     (the carried key equals tau on valid lanes by construction).  Returns
     ``(order, ready)`` tiles; used by both the flat and the stacked-leaf
-    kernels so their traced networks can never drift apart."""
+    kernels so their traced networks can never drift apart.
+
+    Stages and passes are ``fori_loop``s with traced strides, so the
+    compiled program holds two pass bodies whatever the tile size: an
+    unrolled network grows with (passes x vregs per tile) and took
+    minutes to compile at deployment tick sizes."""
     r, c = tau.shape
     vb = valid != 0
-    lane = (jax.lax.broadcasted_iota(jnp.int32, (r, c), 0) * c
-            + jax.lax.broadcasted_iota(jnp.int32, (r, c), 1))
-    key = jnp.where(vb, tau, INF_TIME)
-    idx = lane
-    val = valid
-    n = r * c
-    stages = n.bit_length() - 1
-    for stage in range(stages):
+    row = jax.lax.broadcasted_iota(jnp.int32, (r, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (r, c), 1)
+    lane = row * c + col
+    carry = (jnp.where(vb, tau, INF_TIME), lane, valid)
+    log_c = c.bit_length() - 1
+    stages = (r * c).bit_length() - 1
+
+    def stage_body(stage, carry):
         # block direction: ascending iff bit (stage+1) of the flat lane is
         # 0 — constant within each 2^(stage+1)-aligned bitonic block.
-        asc = (lane & (1 << (stage + 1))) == 0
-        for sub in range(stage, -1, -1):
-            key, idx, val = _cmp_exchange(key, idx, val, 1 << sub, asc)
+        asc = (lane & jnp.left_shift(2, stage)) == 0
+        # strides 2^stage .. 128 move whole rows (sublane rolls) ...
+        n_row = jnp.maximum(stage - log_c + 1, 0)
+        carry = jax.lax.fori_loop(
+            0, n_row, lambda i, cr: _cmp_exchange(
+                cr, jnp.left_shift(1, stage - log_c - i), 0, row, asc),
+            carry)
+        # ... then strides 64 .. 1 move within a row (lane rolls)
+        top = jnp.minimum(stage, log_c - 1)
+        return jax.lax.fori_loop(
+            0, top + 1, lambda i, cr: _cmp_exchange(
+                cr, jnp.left_shift(1, top - i), 1, col, asc),
+            carry)
+
+    key, idx, val = jax.lax.fori_loop(0, stages, stage_body, carry)
     ready = jnp.where((val != 0) & (key <= w), 1, 0).astype(jnp.int32)
     return idx, ready
 
@@ -119,7 +138,7 @@ def _kernel(n_sources, tau_ref, src_ref, valid_ref,
     for s_id in range(n_sources):
         s_max = jnp.max(jnp.where((src == s_id) & vb, tau, -1))
         w = s_max if w is None else jnp.minimum(w, s_max)
-    wmark_ref[0, 0] = w
+    wmark_ref[...] = jnp.full(WMARK_TILE, w, jnp.int32)
 
     order_ref[...], ready_ref[...] = _sort_ready(tau, valid, w)
 
@@ -132,7 +151,7 @@ def _stacked_kernel(tau_ref, valid_ref, rep_ref,
     frontiers, INF on inactive/absent lanes, so ``W = min(rep)`` is the
     Definition-3 composition ``W_root = min_leaf W_leaf``."""
     w = jnp.min(rep_ref[...])
-    wmark_ref[0, 0] = w
+    wmark_ref[...] = jnp.full(WMARK_TILE, w, jnp.int32)
     order_ref[...], ready_ref[...] = _sort_ready(tau_ref[...],
                                                  valid_ref[...], w)
 
@@ -149,10 +168,10 @@ def pallas_specs(n_rows: int):
                   pl.BlockSpec(tile, lambda i: (0, 0))],
         out_specs=[pl.BlockSpec(tile, lambda i: (0, 0)),
                    pl.BlockSpec(tile, lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
+                   pl.BlockSpec(WMARK_TILE, lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct(tile, jnp.int32),
                    jax.ShapeDtypeStruct(tile, jnp.int32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
+                   jax.ShapeDtypeStruct(WMARK_TILE, jnp.int32)],
     )
 
 
@@ -179,10 +198,11 @@ def scalegate_merge(tau, src, valid, *, n_sources: int,
         kern,
         **pallas_specs(rows),
         interpret=interpret,
+        name="scalegate_merge",
     )(tau.reshape(rows, LANES), src.reshape(rows, LANES),
       valid.reshape(rows, LANES))
     return (order2.reshape(n_pad)[:n], ready2.reshape(n_pad)[:n],
-            w2.reshape(1))
+            w2[0, :1])
 
 
 def pallas_specs_stacked(n_rows: int):
@@ -197,10 +217,10 @@ def pallas_specs_stacked(n_rows: int):
                   pl.BlockSpec((1, LANES), lambda i: (0, 0))],
         out_specs=[pl.BlockSpec(tile, lambda i: (0, 0)),
                    pl.BlockSpec(tile, lambda i: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda i: (0, 0))],
+                   pl.BlockSpec(WMARK_TILE, lambda i: (0, 0))],
         out_shape=[jax.ShapeDtypeStruct(tile, jnp.int32),
                    jax.ShapeDtypeStruct(tile, jnp.int32),
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],
+                   jax.ShapeDtypeStruct(WMARK_TILE, jnp.int32)],
     )
 
 
@@ -239,7 +259,8 @@ def scalegate_merge_stacked(tau2, src2, valid2, reports, *,
         _stacked_kernel,
         **pallas_specs_stacked(rows),
         interpret=interpret,
+        name="scalegate_merge_stacked",
     )(tau.reshape(rows, LANES), valid.reshape(rows, LANES), rep)
     return (order2.reshape(n_pad)[:n].reshape(r_in, c_in),
             ready2.reshape(n_pad)[:n].reshape(r_in, c_in),
-            w2.reshape(1))
+            w2[0, :1])

@@ -29,7 +29,7 @@ def _lowering_case():
         args=(jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.int32),
               jnp.zeros((n, w), jnp.float32),
               jnp.zeros((k, s, w), jnp.float32)),
-        specs=pallas_specs(n, w, k, s, tile_k))
+        specs=pallas_specs(n, w, k, s, tile_k, block_n=n))
 
 
 dispatch.register_lint("segment_aggregate", _lowering_case)

@@ -10,23 +10,27 @@ conflicts by construction (a scatter-free formulation: the gather+mask turns
 the random scatter into dense VPU selects, which is the TPU-native shape of
 the paper's per-key f_R loop).
 
-Mosaic-ready layout (ISSUE 5): the hit block is lane-major — keys/slots
-enter as rank-2 ``(1, N)`` rows with N padded to a multiple of 128, the
-one-hot is built with a rank-2 ``broadcasted_iota`` over ``(TK*S, N)``
-(rows = flattened accumulator cells, lanes = hits), and the reduction is a
-single ``dot_general`` contracting the lane dim against ``vals [N, W]`` —
-the MXU shape, with no rank-1 BlockSpecs and no 1-D iota anywhere.
+Mosaic layout: everything is lane-major.  The accumulator is handled as
+``[W, K*S]`` (flattened (key, slot) cells on lanes; the wrapper transposes
+sigma's ``[K, S, W]`` in and out), the hits enter as ``(1, N)`` rows and
+the values as ``[W, N]``, and the grid is 2-D: key tiles (``parallel``) x
+hit blocks of ``BN`` lanes (``arbitrary``, innermost).  Per step the
+program builds the ``(TK*S, BN)`` cell-by-hit one-hot with a rank-2
+``broadcasted_iota`` and contracts the hit lanes against the values on the
+MXU (an NT ``dot_general``, exact at ``HIGHEST`` precision), which yields
+the lane-major ``(W, TK*S)`` contribution directly.  The output tile stays
+resident across the hit blocks and accumulates; it is seeded from ``acc``
+on the first block.  No rank-1 BlockSpecs, no 1-D iota, no ``(N, 1)``
+columns (TPU layouts pad a minor dim of 1 to 128 lanes); VMEM and code
+size per step are bounded by ``TK*S x BN`` whatever the tick size.
 
 Shapes
   keys   i32[N]      virtual key per hit (-1 = dead lane)
   slots  i32[N]      window slot per hit
   vals   f32[N, W]   contribution (1.0 for counts)
-  acc    f32[K, S, W]  accumulator (donated/read-modify-write)
+  acc    f32[K, S, W]  accumulator
 out
   acc'   f32[K, S, W]
-
-Tiling: grid over K tiles; per step VMEM holds the (N,W) block + a
-(TK, S, W) accumulator tile + the (TK*S, N) one-hot.
 """
 
 from __future__ import annotations
@@ -36,47 +40,52 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-LANES = 128                     # hit-block lane padding quantum
+BLOCK_N = 512                   # hit lanes per grid step
+LANES = 128                     # hit-block lane quantum
 
 
-def _kernel(n_slots, tile_k, keys_ref, slots_ref, vals_ref, acc_ref, out_ref):
+def _kernel(n_slots, tile_k, keys_ref, slots_ref, vals_ref, acc_ref,
+            out_ref):
     i = pl.program_id(0)
-    keys = keys_ref[...]                  # [1, N]
-    slots = slots_ref[...]                # [1, N]
-    vals = vals_ref[...]                  # [N, W]
-    lo = i * tile_k
 
-    local = keys - lo                     # key row within this tile
+    @pl.when(pl.program_id(1) == 0)
+    def _seed():
+        out_ref[...] = acc_ref[...]
+
+    keys = keys_ref[...]                  # [1, BN]
+    local = keys - i * tile_k             # key row within this tile
     in_tile = (local >= 0) & (local < tile_k) & (keys >= 0)
-
-    # dense one-hot accumulate: rows = flattened (key, slot) cells of this
-    # tile, lanes = hits; the dot_general contracts the hit lanes on the MXU.
-    flat_idx = local * n_slots + slots    # [1, N]
-    rows = jax.lax.broadcasted_iota(jnp.int32,
-                                    (tile_k * n_slots, keys.shape[1]), 0)
-    onehot = (rows == flat_idx) & in_tile           # [TK*S, N]
-    contrib = jax.lax.dot_general(
-        onehot.astype(vals.dtype), vals, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)         # [TK*S, W]
-    out_ref[...] = acc_ref[...] + contrib.reshape(out_ref.shape)
+    # flattened (key, slot) cell of each hit in this tile; -1 = elsewhere
+    cell = jnp.where(in_tile, local * n_slots + slots_ref[...], -1)
+    rows = jax.lax.broadcasted_iota(
+        jnp.int32, (tile_k * n_slots, keys.shape[1]), 0)
+    onehot = (rows == cell).astype(jnp.float32)       # [TK*S, BN]
+    out_ref[...] += jax.lax.dot_general(
+        vals_ref[...], onehot, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)           # [W, TK*S]
 
 
 def pallas_specs(n: int, w: int, k: int, s: int, tile_k: int,
-                 dtype=jnp.float32):
-    """Grid/Block/out structure, shared with the lowering lint.  The hit
-    block is broadcast to every program (same HBM block); the accumulator
-    tile walks the key axis.  All specs rank >= 2, hits lane-major."""
+                 block_n: int, dtype=jnp.float32):
+    """Grid/Block/out structure, shared with the lowering lint.  Hit blocks
+    walk the inner grid axis; the accumulator tile walks the key axis and
+    stays resident across the hit blocks.  All specs rank >= 2."""
+    cells = tile_k * s
     return dict(
-        grid=(k // tile_k,),
+        grid=(k // tile_k, n // block_n),
         in_specs=[
-            pl.BlockSpec((1, n), lambda i: (0, 0)),      # shared hit block
-            pl.BlockSpec((1, n), lambda i: (0, 0)),
-            pl.BlockSpec((n, w), lambda i: (0, 0)),
-            pl.BlockSpec((tile_k, s, w), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
+            pl.BlockSpec((1, block_n), lambda i, j: (0, j)),
+            pl.BlockSpec((w, block_n), lambda i, j: (0, j)),
+            pl.BlockSpec((w, cells), lambda i, j: (0, i)),
         ],
-        out_specs=pl.BlockSpec((tile_k, s, w), lambda i: (i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((k, s, w), dtype),
+        out_specs=pl.BlockSpec((w, cells), lambda i, j: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((w, k * s), dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
     )
 
 
@@ -90,15 +99,19 @@ def segment_aggregate(keys, slots, vals, acc, *, tile_k: int = 128,
 
     # lane-align the hit block: padding lanes carry key -1 (dead) and zero
     # contribution, so every backend reduces the identical value.
-    n_pad = -(-n // LANES) * LANES
+    block_n = min(BLOCK_N, -(-n // LANES) * LANES)
+    n_pad = -(-n // block_n) * block_n
     if n_pad != n:
         keys = jnp.pad(keys, (0, n_pad - n), constant_values=-1)
         slots = jnp.pad(slots, (0, n_pad - n))
         vals = jnp.pad(vals, ((0, n_pad - n), (0, 0)))
 
     kern = functools.partial(_kernel, s, tile_k)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
-        **pallas_specs(n_pad, w, k, s, tile_k, acc.dtype),
+        **pallas_specs(n_pad, w, k, s, tile_k, block_n, acc.dtype),
         interpret=interpret,
-    )(keys.reshape(1, n_pad), slots.reshape(1, n_pad), vals, acc)
+        name="segment_aggregate",
+    )(keys.reshape(1, n_pad), slots.reshape(1, n_pad), vals.T,
+      acc.transpose(2, 0, 1).reshape(w, k * s))
+    return out.reshape(w, k, s).transpose(1, 2, 0)

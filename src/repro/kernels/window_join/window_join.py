@@ -6,12 +6,17 @@ Each program owns a tile of virtual-key rows of the stored-tuple ring (its
 ``f_mu`` share, via the BlockSpec index map) and compares the whole incoming
 block against its tile: no tuple duplication, disjoint state, deterministic.
 
-Mosaic-ready layout (ISSUE 5): the per-tuple metadata enters as rank-2
-``(B, 1)`` columns (no rank-1 BlockSpecs), B is padded to the f32 sublane
-quantum with tau = INF_TIME lanes (past every freshness horizon: they match
-nothing and count no comparisons, the ``band_join_counts`` neutral
-element), and the kernel body is pure rank->=3 broadcasting — no iota at
-all.
+Mosaic layout: the stored ring enters transposed, ring slot on sublanes
+and keys on lanes (``[R, K]``, payload ``[P, R, K]``), so a tile is a
+stack of full ``(R, TK)`` vreg tiles; the incoming block enters
+lane-replicated, ``(B, TK)`` per field (payload ``[P, B, TK]``), B padded
+to the sublane quantum with tau = INF_TIME rows (past every freshness
+horizon: they match nothing and count no comparisons, the
+``band_join_counts`` neutral element).  The kernel walks the incoming
+tuples with a ``fori_loop``: one ``(1, TK)`` row of each field broadcasts
+over the ring's sublanes (Mosaic broadcasts along one axis at a time), and
+the match mask is reduced over the ring into a lane-major ``(1, TK)`` row
+of ``counts``.  No iota, no rank > 3 values, no gathers.
 
 Shapes
   new_tau  i32[B]            incoming event times (timestamp-sorted tick)
@@ -22,15 +27,18 @@ Shapes
   st_pay   f32[K, R, P]
 outputs
   counts   i32[B, K]         matches of incoming b against key row k
-  comps    i32[K_tiles, 1]   live comparisons per tile (roofline accounting)
+  comps    i32[K_tiles, 1]   live comparisons per tile (roofline accounting;
+                             the kernel writes each tile's count as a full
+                             (8, 128) block — Mosaic's block rule — and the
+                             wrapper keeps one lane)
 
 Band predicate (the [13]/[21] benchmark): matches iff
 ``|newL.phi[a] - newR.phi[a]| <= band`` for a < n_attrs, with stream and
 ``tau_new - tau_stored <= WS`` freshness (purge-on-read).
 
-Tiling: grid over K tiles; per step the program holds (B,P) + (TK,R,P) in
-VMEM.  With B=256, TK=128, R=64, P=2 (f32): 2 KB + 64 KB blocks — far under
-the ~16 MB VMEM budget, MXU-aligned lane dims via padding to 128.
+Tiling: grid over K tiles; per step the program holds the (2 + P) incoming
+(B, TK) blocks plus (R, TK) + (P, R, TK) ring tiles in VMEM.  With B=256,
+TK=128, R=64, P=4 (f32): 768 KB of incoming rows + 192 KB of ring tiles.
 """
 
 from __future__ import annotations
@@ -44,59 +52,60 @@ from jax.experimental import pallas as pl
 from repro.core.watermark import INF_TIME
 
 SUBLANES = 8                    # f32 sublane quantum for the incoming block
+COMPS_TILE = (SUBLANES, 128)    # one full vreg tile per program's count
 
 
 def _kernel(ws, band, n_attrs,
             new_tau_ref, new_src_ref, new_pay_ref,
             st_tau_ref, st_src_ref, st_pay_ref,
             counts_ref, comps_ref):
-    new_tau = new_tau_ref[...]            # [B, 1]
-    new_src = new_src_ref[...]            # [B, 1]
-    new_pay = new_pay_ref[...]            # [B, P]
-    st_tau = st_tau_ref[...]              # [TK, R]
-    st_src = st_src_ref[...]              # [TK, R]
-    st_pay = st_pay_ref[...]              # [TK, R, P]
+    st_tau = st_tau_ref[...]              # [R, TK]
+    st_src = st_src_ref[...]              # [R, TK]
+    st_pay = [st_pay_ref[a] for a in range(n_attrs)]   # n_attrs x [R, TK]
+    live = st_tau >= 0
 
-    # freshness + stream predicates: [B, TK, R]
-    fresh = st_tau[None] + ws >= new_tau[:, :, None]
-    live = (st_tau[None] >= 0) & fresh
-    opp = live & (st_src[None] != new_src[:, :, None])
+    def body(b, comps):
+        row = (pl.ds(b, 1), slice(None))
+        # freshness + stream predicates of incoming tuple b: [R, TK]
+        opp = (live & (st_tau + ws >= new_tau_ref[row])
+               & (st_src != new_src_ref[row]))
+        # band predicate on the first n_attrs payload attributes
+        hit = opp
+        for a in range(n_attrs):
+            hit = hit & (jnp.abs(new_pay_ref[(a,) + row] - st_pay[a]) <= band)
+        counts_ref[row] = jnp.sum(hit.astype(jnp.int32), axis=0,
+                                  keepdims=True)
+        return comps + jnp.sum(opp.astype(jnp.int32), axis=0, keepdims=True)
 
-    # band predicate on the first n_attrs payload attributes
-    ok = jnp.ones_like(opp)
-    for a in range(n_attrs):
-        d = new_pay[:, None, None, a] - st_pay[None, :, :, a]
-        ok = ok & (jnp.abs(d) <= band)
-
-    hit = opp & ok
-    counts_ref[...] = jnp.sum(hit.astype(jnp.int32), axis=-1)
-    comps_ref[0, 0] = jnp.sum(opp.astype(jnp.int32))
+    comps = jax.lax.fori_loop(0, counts_ref.shape[0], body,
+                              jnp.zeros((1, st_tau.shape[1]), jnp.int32))
+    comps_ref[...] = jnp.full((1,) + COMPS_TILE, jnp.sum(comps), jnp.int32)
 
 
 def pallas_specs(b: int, p: int, k: int, r: int, tile_k: int):
     """Grid/Block/out structure, shared with the lowering lint.  The
     incoming block is broadcast to every program; the stored-ring tiles
-    walk the key axis.  All specs rank >= 2."""
+    walk the key (lane) axis.  All specs rank >= 2."""
     grid = (k // tile_k,)
     return dict(
         grid=grid,
         in_specs=[
             # the shared tuple block: every program maps the same HBM block
-            pl.BlockSpec((b, 1), lambda i: (0, 0)),
-            pl.BlockSpec((b, 1), lambda i: (0, 0)),
-            pl.BlockSpec((b, p), lambda i: (0, 0)),
-            # the program's key-row tile (its f_mu share)
-            pl.BlockSpec((tile_k, r), lambda i: (i, 0)),
-            pl.BlockSpec((tile_k, r), lambda i: (i, 0)),
-            pl.BlockSpec((tile_k, r, p), lambda i: (i, 0, 0)),
+            pl.BlockSpec((b, tile_k), lambda i: (0, 0)),
+            pl.BlockSpec((b, tile_k), lambda i: (0, 0)),
+            pl.BlockSpec((p, b, tile_k), lambda i: (0, 0, 0)),
+            # the program's key-column tile (its f_mu share)
+            pl.BlockSpec((r, tile_k), lambda i: (0, i)),
+            pl.BlockSpec((r, tile_k), lambda i: (0, i)),
+            pl.BlockSpec((p, r, tile_k), lambda i: (0, 0, i)),
         ],
         out_specs=[
             pl.BlockSpec((b, tile_k), lambda i: (0, i)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1,) + COMPS_TILE, lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, k), jnp.int32),
-            jax.ShapeDtypeStruct((grid[0], 1), jnp.int32),
+            jax.ShapeDtypeStruct((grid[0],) + COMPS_TILE, jnp.int32),
         ],
     )
 
@@ -118,11 +127,15 @@ def window_join(new_tau, new_src, new_pay, st_tau, st_src, st_pay, *,
         new_src = jnp.pad(new_src, (0, b_pad - b))
         new_pay = jnp.pad(new_pay, ((0, b_pad - b), (0, 0)))
 
+    def lanes(x):                       # replicate a [..., B] field
+        return jnp.broadcast_to(x[..., None], x.shape + (tile_k,))
+
     kern = functools.partial(_kernel, ws, band, n_attrs)
     counts, comps = pl.pallas_call(
         kern,
         **pallas_specs(b_pad, p, k, r, tile_k),
         interpret=interpret,
-    )(new_tau.reshape(b_pad, 1), new_src.reshape(b_pad, 1), new_pay,
-      st_tau, st_src, st_pay)
-    return counts[:b], comps
+        name="window_join",
+    )(lanes(new_tau), lanes(new_src), lanes(new_pay.T), st_tau.T, st_src.T,
+      st_pay.transpose(2, 1, 0))
+    return counts[:b], comps[:, 0, :1]

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import compile_cache
 from repro.configs import ARCHS, canon, get_config
 from repro.launch import specs as S
 from repro.launch.mesh import make_production_mesh
@@ -200,6 +201,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])

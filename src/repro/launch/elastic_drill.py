@@ -51,6 +51,7 @@ import tempfile
 import numpy as np
 import jax
 
+from repro import compile_cache
 from repro import api
 from repro.core.controller import Reconfiguration, active_mask, balanced_fmu
 from repro.core.elastic import vsn_switch_bytes
@@ -73,6 +74,7 @@ def base_cfg(k: int) -> api.RuntimeConfig:
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", type=int, default=0,
                     help="also run the straggler drill on an N-device mesh")

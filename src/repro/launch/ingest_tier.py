@@ -28,6 +28,7 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.data import datagen
 from repro.ingest import (IngestTier, collect_tuples, emitted_taus,
                           single_gate_stream)
@@ -44,6 +45,7 @@ def make_stream(args):
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--leaves", type=int, default=3)
     ap.add_argument("--sources", type=int, default=6)

@@ -49,6 +49,7 @@ import sys
 import numpy as np
 import jax
 
+from repro import compile_cache
 from repro import api
 from repro import obs as _obs
 from repro.core.async_runtime import run_sync
@@ -165,6 +166,7 @@ class _Recording:
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--ticks", type=int, default=24)
     ap.add_argument("--tick", type=int, default=256, help="tuples per tick")

@@ -37,7 +37,13 @@ def make_host_mesh(data: int = 1, model: int = 1):
 
 def make_stream_mesh(n_shards: int = None, axis: str = STREAM_AXIS):
     """1-D mesh over ``n_shards`` local devices for the VSN instance axis
-    (defaults to every visible device)."""
+    (defaults to every visible device).
+
+    The axis is ``Auto``: the pipeline places sigma and the replicated
+    tables itself and ``shard_map`` states every spec, while ``Explicit``
+    axes (``jax.make_mesh``'s default) turn sharding into part of each
+    array's type and reject the step's mixed sharded/replicated updates
+    (``core.runtime.inject_ctrl``)."""
     n_shards = n_shards or len(jax.devices())
     avail = len(jax.devices())
     if n_shards > avail:
@@ -45,7 +51,8 @@ def make_stream_mesh(n_shards: int = None, axis: str = STREAM_AXIS):
             f"mesh wants {n_shards} devices but only {avail} are visible; "
             f"set XLA_FLAGS=--xla_force_host_platform_device_count={n_shards} "
             f"before the first jax import to emulate them on CPU")
-    return jax.make_mesh((n_shards,), (axis,))
+    return jax.make_mesh((n_shards,), (axis,),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
 
 # ---------------------------------------------------------------------------

@@ -35,6 +35,7 @@ import dataclasses
 import json
 import sys
 
+from repro import compile_cache
 from repro.configs import ARCHS, canon, get_config
 from repro.models.config import ModelConfig
 
@@ -199,6 +200,7 @@ def analyse(rec: dict, peak_mem=None) -> dict:
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--cell", default=None)

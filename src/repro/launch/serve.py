@@ -16,12 +16,14 @@ for comparison.
 import argparse
 import sys
 
+from repro import compile_cache
 from repro.api import RuntimeConfig, build_runtime
 from repro.io.sources import RateSchedule
 from repro.serving import RequestSource, ServingConfig
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b")
     ap.add_argument("--reduced", action="store_true")

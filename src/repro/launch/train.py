@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.checkpoint import checkpoint as C
 from repro.configs import canon, get_config, reduced
 from repro.data import datagen
@@ -35,6 +36,7 @@ from repro.optim import adamw
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-14b")
     ap.add_argument("--steps", type=int, default=100)

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 
 from repro.models.config import ModelConfig
 from repro.models.layers import init_dense, swiglu
@@ -171,7 +170,7 @@ def _vsn_moe(p, x2, cfg: ModelConfig):
                              n_shards=n_shards)
     x_spec = P(dp_spec[0] if len(dp_spec) else None, None)
     e_spec = P(axis, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, P(None, None), e_spec, e_spec, e_spec),
         out_specs=(x_spec, P()),

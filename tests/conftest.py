@@ -7,23 +7,19 @@ except ImportError:                    # deterministic minimal stand-in
     from _hypothesis_fallback import install as _install_hypothesis
     _install_hypothesis()
 
-import os
-import pathlib
-
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-# Persistent XLA compilation cache: the suite is compile-dominated on CPU
-# (hundreds of distinct jit shapes), and the cache cuts repeat tier-1 runs
-# to a fraction of the cold time.  Opt out with REPRO_NO_COMPILE_CACHE=1.
-if not os.environ.get("REPRO_NO_COMPILE_CACHE"):
-    _cache = pathlib.Path(__file__).parent.parent / ".jax_cache"
-    jax.config.update("jax_compilation_cache_dir", str(_cache))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-
+from repro import compile_cache
 from repro.core import tuples as T
+
+# Persistent XLA compilation cache (repro.compile_cache): the suite is
+# compile-dominated on CPU (hundreds of distinct jit shapes), and the cache
+# cuts repeat tier-1 runs to a fraction of the cold time.
+compile_cache.enable()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 def make_stream_batch(taus, keys=None, payload=None, source=None, kmax=1):

@@ -273,6 +273,21 @@ def test_save_load_stream_roundtrip(tmp_path):
                                       np.asarray(b.payload))
 
 
+def test_sink_keeps_large_payload_values_exact():
+    """Key ids and counts above ~1678 survive the sink's 4-decimal
+    rounding unchanged (float32 rounding turned 26869 into 26868.998)."""
+    from repro.core.operator import Outputs
+    from repro.io.sinks import flatten_outputs
+
+    pay = np.array([[26869., 3.], [65535., 49152.], [0.25, 1.]], np.float32)
+    outs = Outputs(tau=np.array([1000, 1000, 2000], np.int32), payload=pay,
+                   valid=np.array([True, True, True]),
+                   count=np.int32(3), overflow=np.int32(0))
+    assert flatten_outputs(outs) == [(1000, (26869.0, 3.0)),
+                                     (1000, (65535.0, 49152.0)),
+                                     (2000, (0.25, 1.0))]
+
+
 def test_rate_schedule():
     s = RateSchedule(((2, 100.0), (3, 900.0)))
     assert [s.rate_at(i) for i in range(7)] == [100., 100., 900., 900.,

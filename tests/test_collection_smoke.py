@@ -13,7 +13,7 @@ TESTS_DIR = pathlib.Path(__file__).parent
 TEST_MODULES = sorted(p.stem for p in TESTS_DIR.glob("test_*.py"))
 
 LIB_MODULES = [
-    "repro.compat",
+    "repro.launch.mesh",
     "repro.kernels.dispatch",
     "repro.kernels.scalegate_merge.ops",
     "repro.kernels.segment_aggregate.ops",
@@ -43,15 +43,20 @@ def test_library_module_imports(mod):
 
 
 def test_shard_map_call_sites_use_compat():
-    """No module may import shard_map from jax directly — only via compat
-    (the 0.4.x/0.6 move is exactly what broke the seed)."""
+    """Every shard_map call site uses the installed jax's own
+    ``jax.shard_map``: no module imports the experimental namespace or a
+    version shim (the repo pins one jax; a second spelling is how the
+    0.4.x/0.6 move once broke collection)."""
     src = pathlib.Path(__file__).parent.parent / "src"
-    offenders = []
+    offenders, callers = [], []
     for py in src.rglob("*.py"):
-        if py.name == "compat.py":
-            continue
         text = py.read_text()
-        if ("from jax import shard_map" in text
-                or "from jax.experimental.shard_map" in text):
+        if ("from jax.experimental.shard_map" in text
+                or "repro.compat" in text):
             offenders.append(str(py))
+        if "shard_map(" in text:
+            callers.append(py.name)
+            if "jax.shard_map(" not in text:
+                offenders.append(str(py))
     assert not offenders, offenders
+    assert {"vsn.py", "moe.py"} <= set(callers), callers

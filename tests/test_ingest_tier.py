@@ -19,6 +19,7 @@ The contracts under test:
     merge tolerates either.
 """
 
+import os
 import threading
 import time
 import warnings
@@ -131,6 +132,34 @@ def test_process_workers_parity():
     assert_ordered(outs)
     oracle = single_gate_stream(batches, N_SRC, cap=96)
     assert collect_tuples(outs) == collect_tuples(oracle)
+
+
+def test_process_leaf_runs_on_host_cpu(monkeypatch):
+    """A spawned leaf pins itself to the CPU and the xla backend, whatever
+    the parent's environment asks for: on a TPU machine the chip belongs
+    to the parent process alone.  The children inherit
+    ``REPRO_KERNEL_BACKEND=pallas`` (the compiled kernels, which cannot run
+    on a CPU); the parent's own root merge runs xla by argument.  The
+    leaves' ``leaf_start`` events, shipped back on the round stream, report
+    what they actually ran."""
+    from repro import obs
+    from repro.obs import ObsConfig
+
+    monkeypatch.setenv("REPRO_KERNEL_BACKEND", "pallas")
+    prev = obs.get()
+    o = obs.install(ObsConfig(enabled=True))
+    try:
+        batches = agg_stream(n_ticks=2)
+        tier = IngestTier(batches, N_SRC, 2, **tier_kw(worker="process",
+                                                       backend="xla"))
+        outs = list(tier)
+    finally:
+        obs.set_current(prev)
+    assert_ordered(outs)
+    starts = [e for e in o.flight.events if e["kind"] == "leaf_start"]
+    assert len(starts) == 2
+    assert {(e["platform"], e["backend"]) for e in starts} == {("cpu", "xla")}
+    assert os.getpid() not in {e["pid"] for e in starts}
 
 
 # ------------------------------------------------- runtime integration ----
@@ -257,7 +286,9 @@ def test_overflow_under_remove_host_flush():
 
 # ------------------------------------------- merge_order tie contract -----
 
-def tied_batch(n=32, n_sources=4, seed=7):
+def tied_batch(n=37, n_sources=4, seed=7):
+    """Heavy-tie tick; the default length is not a power of two, so the
+    Pallas backends run the merge kernel's internal padding path."""
     rng = np.random.default_rng(seed)
     tau = np.sort(rng.integers(0, 6, n)).astype(np.int32)   # heavy ties
     src = rng.integers(0, n_sources, n).astype(np.int32)

@@ -1,12 +1,11 @@
-"""Mosaic lowering lint as a tier-1 regression gate (ISSUE 5 satellite).
+"""Mosaic lowering lint as a tier-1 regression gate.
 
 A rank-1 BlockSpec or a 1-D iota/``jnp.arange`` can never silently
 reappear in any registered kernel: the structural lint runs over every
 ``dispatch.register_lint`` case on every tier-1 run, and the deliberately-
 bad fixtures below pin that the lint actually *catches* the offenders the
-Pallas interpreter hides.  The full-Mosaic AOT smoke at the bottom runs
-only under ``REPRO_TPU=1`` with TPU hardware attached (the CI job stub is
-ready for bring-up).
+Pallas interpreter hides.  ``test_chip_compile.py`` compiles the same
+kernels for a described TPU v5e.
 """
 
 import jax
@@ -113,22 +112,3 @@ def test_lint_flags_missing_pallas_call():
     report = lowering.lint_case(case)
     assert not report.ok and any("no pallas_call" in e
                                  for e in report.errors)
-
-
-# ------------------------------------------------------- AOT Mosaic smoke --
-
-@pytest.mark.skipif(not lowering.smoke_requested(),
-                    reason="REPRO_TPU=1 not set (TPU bring-up job only)")
-@pytest.mark.parametrize("name", KERNELS)
-def test_lowering_smoke_full_mosaic(name):
-    """jit(...).lower() through the real Mosaic pipeline — the bring-up
-    gate for the `pallas` (non-interpret) backend on hardware.
-
-    REPRO_TPU=1 asserts the operator *meant* to run on TPU hardware: a
-    missing TPU backend is then a red job, not a silently-green all-skip
-    (the CI stub must not look like a passed Mosaic smoke)."""
-    case = dispatch.lint_cases()[name]()
-    skip = lowering.lowering_smoke(case)
-    if skip is not None:
-        pytest.fail(f"REPRO_TPU=1 but {skip} — point the job's runner at "
-                    "TPU hardware (README runbook step 5)")
