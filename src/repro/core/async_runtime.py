@@ -23,6 +23,15 @@ host round-trip per tick.  This runtime makes the stream *live*:
   the loop.  Detection→switch latency (decision wall-clock to the first
   observed epoch switch) is measured per reconfiguration.
 
+With tracing on, the spans split each side of the queue into work and
+waiting: ``ingest.stage`` (work) and ``ingest.blocked`` (the put under
+backpressure) on the ingest thread, tagged with the dispatch's first tick;
+``runtime.wait`` (the get, starved of ingest), ``runtime.dispatch`` and
+``runtime.drain`` (tagged with the dispatch's tick id, and the dispatch
+with the epoch it injects) on the step loop.  A reconfiguration records
+``reconfig.behind`` (decision to the drain of the dispatch before its own)
+and ``reconfig.pending`` (decision to the drain that observes its switch).
+
 ``run_sync`` is the measured baseline: the same semantics as a plain
 host loop (generate, step, block on outputs), so async-vs-sync throughput
 isolates the overlap gain and async-vs-sync output sets pin correctness.
@@ -236,17 +245,18 @@ class AsyncStreamRuntime:
                 for i, b in enumerate(self.source):
                     if max_ticks is not None and i >= max_ticks:
                         break
-                    with _obs.span("ingest.stage"):
-                        meta = tick_meta(b, self.tick0 + i, n_inputs,
-                                         k_virt, frontier,
-                                         with_hist=with_hist)
+                    tick_id = self.tick0 + i
+                    with _obs.span("ingest.stage", tick=tick_id):
+                        meta = tick_meta(b, tick_id, n_inputs, k_virt,
+                                         frontier, with_hist=with_hist)
                         staged = self.pipeline.stage(b)   # async transfer
                     tl = _obs.exemplars()
                     if tl is not None:
                         ok = np.asarray(b.valid) & ~np.asarray(b.is_control)
                         tl.scan(np.asarray(b.source), np.asarray(b.tau),
                                 ok, "stage", tick_id=meta.tick_id)
-                    self.queue.put(StagedTick(meta, staged))
+                    with _obs.span("ingest.blocked", tick=tick_id):
+                        self.queue.put(StagedTick(meta, staged))
         except BaseException as e:              # surfaced after join()
             self._ingest_error = e
             _obs.event("ingest_error", error=repr(e))
@@ -270,12 +280,14 @@ class AsyncStreamRuntime:
                 return
             n_pad = K - len(group)
             b0 = group[0]
-            with _obs.span("ingest.stage"):
+            tick_id = metas[0].tick_id
+            with _obs.span("ingest.stage", tick=tick_id):
                 ticks = group + [T.empty_batch(b0.batch, b0.kmax,
                                                b0.payload_width)] * n_pad
                 stack = self.pipeline.stage_super(ticks)   # async transfer
-            self.queue.put(StagedSuper(metas=metas, stack=stack,
-                                       n_pad=n_pad))
+            with _obs.span("ingest.blocked", tick=tick_id):
+                self.queue.put(StagedSuper(metas=metas, stack=stack,
+                                           n_pad=n_pad))
             group, metas = [], []
 
         for i, b in enumerate(self.source):
@@ -326,7 +338,7 @@ class AsyncStreamRuntime:
         tick — is subtracted so a paced/starved source does not inflate the
         reported tick latency."""
         tick_id, switched, inst_load, meta, t_dispatch = pending
-        with _obs.span("runtime.drain"):
+        with _obs.span("runtime.drain", tick=tick_id):
             sw = bool(np.asarray(switched))
             load = (np.asarray(inst_load) if inst_load is not None
                     else self._host_inst_load(meta.key_hist))
@@ -367,15 +379,19 @@ class AsyncStreamRuntime:
                 _obs.event("switch", tick_id=tick_id, epoch=int(rc.epoch),
                            n_active=int(self._active_shadow.sum()))
 
-    def _decide(self, meta: TickMeta) -> Optional[Reconfiguration]:
+    def _decide(self, meta: TickMeta
+                ) -> Tuple[Optional[Reconfiguration], float]:
+        """The controller's reconfiguration for this dispatch, if any, and
+        the ``time.perf_counter`` stamp of its decision."""
         if self.controller is None:
-            return None
+            return None, 0.0
         hint = None
         if hasattr(self.source, "rate_hint"):
             hint = self.source.rate_hint(meta.tick_id)
         if hint is None and len(self.metrics.records) < 2:
-            return None    # no rate signal yet: a measured rate of 0.0 at
-            # stream start would read as idle and trigger a bogus scale-down
+            return None, 0.0   # no rate signal yet: a measured rate of 0.0
+            # at stream start would read as idle and trigger a bogus
+            # scale-down
         breaches = tuple(self._pending_breaches)
         self._pending_breaches.clear()
         snap = self.metrics.snapshot(
@@ -383,7 +399,8 @@ class AsyncStreamRuntime:
             backlog_tuples=float(self.queue.depth * meta.n_tuples),
             slo_breaches=breaches)
         with _obs.span("controller.decide"):
-            return self.controller.observe_live(snap)
+            rc = self.controller.observe_live(snap)
+            return rc, time.perf_counter()
 
     # -- the loop -----------------------------------------------------------
     def run(self, max_ticks: Optional[int] = None) -> RunReport:
@@ -396,7 +413,8 @@ class AsyncStreamRuntime:
             while True:
                 t_wait = time.perf_counter()
                 try:
-                    item = self.queue.get()
+                    with _obs.span("runtime.wait"):
+                        item = self.queue.get()
                 except QueueClosed:     # ingest done and every tick drained
                     break
                 idle_s = time.perf_counter() - t_wait
@@ -412,9 +430,12 @@ class AsyncStreamRuntime:
                     with _obs.span("runtime.checkpoint"):
                         self.checkpointer.maybe_save(meta.tick_id,
                                                      meta.frontier_before)
-                rc = self._decide(meta)
+                rc, t_decide = self._decide(meta)
+                ids = {"tick": meta.tick_id}
+                if rc is not None:
+                    ids["epoch"] = int(rc.epoch)
                 t0 = time.perf_counter()
-                with _obs.span("runtime.dispatch"):
+                with _obs.span("runtime.dispatch", **ids):
                     if isinstance(item, StagedSuper):
                         out = self.pipeline.run_persistent_staged(
                             item.stack, reconfig=rc, reconfig_at=0,
@@ -433,8 +454,8 @@ class AsyncStreamRuntime:
                     tl.mark_tick(meta.tick_id, "dispatch")
                 if rc is not None:
                     self.reconfig_trace.append((meta.tick_id, rc))
-                    self.metrics.record_detection(rc.epoch,
-                                                  meta.tick_id, rc)
+                    self.metrics.record_detection(rc.epoch, meta.tick_id,
+                                                  rc, t=t_decide)
                     _obs.event("reconfig", tick_id=meta.tick_id,
                                epoch=int(rc.epoch),
                                n_active=int(np.asarray(rc.active).sum()))
@@ -443,6 +464,11 @@ class AsyncStreamRuntime:
                     # tick T-1 syncs while T computes; the wait for T's
                     # arrival was source idle time, not T-1's latency
                     self._drain(pending, idle_s=idle_s)
+                    if rc is not None:
+                        # the device work queued ahead of this dispatch
+                        _obs.interval("reconfig.behind", t_decide,
+                                      time.perf_counter(),
+                                      epoch=int(rc.epoch))
                 pending = (meta.tick_id, switched, inst_load, meta, t0)
             if pending is not None:
                 self._drain(pending)
@@ -518,7 +544,7 @@ def run_sync(pipeline, source, sink=None, controller=None,
             b, reconfig=rc, frontier=meta.frontier_before)
         if rc is not None:
             trace.append((tick_id, rc))
-            metrics.record_detection(rc.epoch, tick_id, rc)
+            metrics.record_detection(rc.epoch, tick_id, rc, t=t0)
         jax.block_until_ready((o1, o2))        # the synchronous host loop
         sw = bool(np.asarray(switched))
         load = None if inst_load is None else np.asarray(inst_load)

@@ -24,10 +24,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro import obs as _obs
 from repro.core import scalegate
 from repro.core import tuples as T
 
@@ -159,8 +160,10 @@ class LeafGate:
     # -- per-round work ------------------------------------------------------
     def push_round(self, round_id: int, slice_np: Optional[Dict] = None,
                    final: bool = False) -> LeafOut:
-        """Push this round's routed tuples (possibly none) and report."""
-        parts: List[Dict[str, np.ndarray]] = []
+        """Push this round's routed tuples (possibly none) and report.
+        Every chunk is pushed before any result is read back, so the
+        round's one wait for the device is the span ``leaf.fetch``."""
+        outs = []
         lanes = 0 if slice_np is None else slice_np["tau"].shape[0]
         off = 0
         while True:
@@ -172,14 +175,18 @@ class LeafGate:
                 chunk = pad_np({f: slice_np[f][off:off + n] for f in FIELDS},
                                self.chunk)
             self.state, out = self._push(self.state, np_to_batch(chunk))
-            parts.append(compact_np(batch_to_np(out)))
+            outs.append(out)
             off += self.chunk
             if off >= lanes:
                 break
-        ready = concat_np(parts, self.kmax, self.payload_width)
-        return LeafOut(self.leaf_id, round_id, ready,
-                       wmark=int(self.state.wmark.value()),
-                       overflow=int(self.state.overflow), final=final)
+        with _obs.span("leaf.fetch", round=round_id):
+            fetched = [batch_to_np(out) for out in outs]
+            wmark = int(self.state.wmark.value())
+            overflow = int(self.state.overflow)
+        ready = concat_np([compact_np(d) for d in fetched], self.kmax,
+                          self.payload_width)
+        return LeafOut(self.leaf_id, round_id, ready, wmark=wmark,
+                       overflow=overflow, final=final)
 
     # -- ESG membership ------------------------------------------------------
     def _mask(self, src: int):
@@ -229,7 +236,6 @@ def run_gate_loop(gate: LeafGate, recv, send, ship_obs: bool = False) -> None:
     observability payload to each outgoing ``LeafOut``; thread workers
     share the parent's registry and must NOT ship (double-counting).
     """
-    from repro import obs as _obs
     from repro.io.queues import QueueClosed
 
     def answer(out: LeafOut) -> None:
@@ -256,12 +262,12 @@ def run_gate_loop(gate: LeafGate, recv, send, ship_obs: bool = False) -> None:
                 s = msg[2]
                 tl.scan(s["source"], s["tau"],
                         s["valid"] & ~s["is_control"], "leaf_push")
-            with _obs.span("leaf.push"):
+            with _obs.span("leaf.push", round=msg[1]):
                 out = gate.push_round(msg[1], msg[2])
             answer(out)
         elif kind == "cmd":
             leaving = gate.apply(msg[2])
-            with _obs.span("leaf.push"):
+            with _obs.span("leaf.push", round=msg[1]):
                 out = gate.push_round(msg[1], None, final=leaving)
             answer(out)
             if leaving:
@@ -283,7 +289,6 @@ def process_worker_main(cfg: Dict, in_q, out_q) -> None:
     """
     import jax
 
-    from repro import obs as _obs
     from repro.kernels import dispatch
     from repro.ingest.channels import MP_CLOSE
     from repro.io.queues import QueueClosed

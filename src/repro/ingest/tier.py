@@ -595,7 +595,7 @@ class IngestTier:
                             tl.scan(r["source"], r["tau"],
                                     r["valid"] & ~r["is_control"],
                                     "root_merge")
-                with _obs.span("root.merge"):
+                with _obs.span("root.merge", round=rec.round_id):
                     self.root.apply_pre(rec.root_ops)
                     out = self.root.push(outs)
                     self.root.apply_post(rec.root_ops)

@@ -62,10 +62,10 @@ class MetricsBus:
         self.total_tuples = 0
         self._lat_sketch = Histogram()         # full-run latency (seconds)
         # detection -> switch accounting: a controller decision is
-        # "detected" when its Reconfiguration is injected; "switched" when
-        # the runtime first observes switched=True for it (Alg. 4's
-        # watermark barrier having passed gamma).  Entries carry the rc so
-        # record_switch can hand the caller what the switch committed.
+        # "detected" at the decision's stamp; "switched" when the runtime
+        # first observes switched=True for it (Alg. 4's watermark barrier
+        # having passed gamma).  Entries carry the rc so record_switch can
+        # hand the caller what the switch committed.
         self._pending_detections: List[tuple] = []  # (epoch, t_wall, tick, rc)
         self.detect_to_switch_ms: List[float] = []
         self.detect_to_switch_ticks: List[int] = []
@@ -108,9 +108,13 @@ class MetricsBus:
             reg.set_gauge("bus.queue_depth", queue_depth)
             reg.set_gauge("bus.n_active", n_active)
 
-    def record_detection(self, epoch: int, tick_id: int, rc=None):
+    def record_detection(self, epoch: int, tick_id: int, rc=None,
+                         t: Optional[float] = None):
+        """A reconfiguration injected at ``tick_id``; ``t`` is the
+        ``time.perf_counter`` stamp of the controller's decision (now if
+        None)."""
         self._pending_detections.append(
-            (epoch, time.perf_counter(), tick_id, rc))
+            (epoch, time.perf_counter() if t is None else t, tick_id, rc))
         _obs.counter_inc("bus.detections")
 
     def record_switch(self, tick_id: int):
@@ -124,10 +128,10 @@ class MetricsBus:
         resolved = [d for d in self._pending_detections if d[2] <= tick_id]
         self._pending_detections = [d for d in self._pending_detections
                                     if d[2] > tick_id]
-        for _, t0, tick0, _rc in resolved:
+        for epoch, t0, tick0, _rc in resolved:
             self.detect_to_switch_ms.append((now - t0) * 1e3)
             self.detect_to_switch_ticks.append(tick_id - tick0)
-            _obs.observe("bus.detect_to_switch_s", now - t0)
+            _obs.interval("reconfig.pending", t0, now, epoch=int(epoch))
         if resolved:
             _obs.counter_inc("bus.switches")
         return [rc for _, _, _, rc in resolved if rc is not None]

@@ -5,7 +5,8 @@ One ``Obs`` object bundles the instruments sharing a registry:
 - ``obs.registry`` — counters / gauges / quantile-sketch histograms with a
   versioned-schema snapshot (JSON + Prometheus text); see ``registry.py``.
 - ``obs.tracer`` — nested spans; per-stage latency quantiles land in
-  ``span.*`` histograms; see ``trace.py``.
+  ``span.*`` histograms, and each span is also a profiler
+  ``TraceAnnotation`` carrying its ids; see ``trace.py``.
 - ``obs.flight`` — ring buffer of structured events, JSON-dumped on
   crash/chaos failure or on demand; see ``flight.py``.
 - ``obs.sampler`` (optional) — adaptive head sampler thinning span/event
@@ -21,8 +22,8 @@ One ``Obs`` object bundles the instruments sharing a registry:
 
 A process-global current ``Obs`` is installed with ``install(ObsConfig)``
 (or ``set_current`` for an existing instance). Instrumented call sites use
-the module-level helpers ``span()`` / ``event()`` / ``counter_inc()`` /
-``gauge_set()``: when nothing is installed (the default) they are a single
+the module-level helpers ``span()`` / ``interval()`` / ``event()`` /
+``counter_inc()`` / ``gauge_set()``: when nothing is installed (the default) they are a single
 global load + ``is None`` test, so the off path costs nanoseconds.
 
 Cross-process propagation: a child ingest-leaf process installs its own
@@ -52,7 +53,8 @@ from .slo import SloBreach, SloEngine, SloRule
 
 __all__ = [
     "ObsConfig", "Obs", "install", "get", "set_current",
-    "span", "event", "counter_inc", "gauge_set", "observe", "exemplars",
+    "span", "interval", "event", "counter_inc", "gauge_set", "observe",
+    "exemplars",
     "drain_payload", "ingest_payload",
     "MetricsRegistry", "Tracer", "FlightRecorder",
     "HeadSampler", "ExemplarTimelines", "is_exemplar",
@@ -267,13 +269,25 @@ def get() -> Optional[Obs]:
 
 # ------------------------------------- near-free instrumentation helpers --
 
-def span(name: str):
-    """Open a tracing span on the current Obs; no-op singleton if obs or
-    tracing is off (one global load + None test on the off path)."""
+def span(name: str, **ids):
+    """Open a tracing span on the current Obs, tagged with the ids of the
+    work it belongs to (``round=``, ``tick=``, ``epoch=``); no-op singleton
+    if obs or tracing is off (one global load + None test on the off
+    path)."""
     o = _current
     if o is None or not o.tracer.enabled:
         return _NULL_SPAN
-    return o.tracer.span(name)
+    return o.tracer.span(name, **ids)
+
+
+def interval(name: str, t0: float, t1: float, **ids) -> None:
+    """Record a span from its ``time.perf_counter`` start and end stamps,
+    taken in different calls (``Tracer.interval``); no-op when obs or
+    tracing is off."""
+    o = _current
+    if o is None or not o.tracer.enabled:
+        return
+    o.tracer.interval(name, t0, t1, **ids)
 
 
 def event(kind: str, **fields) -> None:
