@@ -38,7 +38,7 @@ class KernelCase:
     name: str
     fn: Callable                  # full kernel entry; takes ``args`` arrays
     args: tuple                   # representative (small, padded) inputs
-    specs: dict                   # grid / in_specs / out_specs / out_shape
+    specs: dict                   # grid (or grid_spec) / specs / out_shape
 
 
 @dataclasses.dataclass
@@ -56,10 +56,14 @@ def _as_list(x) -> list:
 
 
 def _spec_errors(case: KernelCase) -> List[str]:
-    """Rank-1 BlockSpecs / out_shapes are Mosaic-unlowerable: reject."""
+    """Rank-1 BlockSpecs / out_shapes are Mosaic-unlowerable: reject.
+    The BlockSpecs sit in ``specs`` itself or in its ``grid_spec``."""
     errs = []
+    grid_spec = case.specs.get("grid_spec")
     for field in ("in_specs", "out_specs"):
-        for i, bs in enumerate(_as_list(case.specs.get(field, ()))):
+        specs = (case.specs.get(field, ()) if grid_spec is None
+                 else getattr(grid_spec, field))
+        for i, bs in enumerate(_as_list(specs)):
             shape = tuple(bs.block_shape)
             if len(shape) < 2:
                 errs.append(f"{field}[{i}]: rank-{len(shape)} BlockSpec "

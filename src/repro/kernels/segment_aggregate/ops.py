@@ -7,8 +7,8 @@ import jax.numpy as jnp
 
 from repro.kernels import dispatch
 from repro.kernels.segment_aggregate.ref import segment_aggregate_ref
-from repro.kernels.segment_aggregate.segment_aggregate import (pallas_specs,
-                                                               segment_aggregate)
+from repro.kernels.segment_aggregate.segment_aggregate import (
+    BLOCK_N, pallas_specs, segment_aggregate)
 
 
 def _xla(keys, slots, vals, acc, *, tile_k=None):
@@ -22,14 +22,15 @@ dispatch.register_kernel("segment_aggregate",
 
 def _lowering_case():
     from repro.kernels import lowering
-    n, w, k, s, tile_k = 128, 2, 256, 4, 128
+    # two key tiles x two hit blocks: a visit grid of three
+    n, w, k, s, tile_k = 1024, 2, 256, 4, 128
     return lowering.KernelCase(
         "segment_aggregate",
         fn=functools.partial(segment_aggregate, tile_k=tile_k),
         args=(jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.int32),
               jnp.zeros((n, w), jnp.float32),
               jnp.zeros((k, s, w), jnp.float32)),
-        specs=pallas_specs(n, w, k, s, tile_k, block_n=n))
+        specs=pallas_specs(n, w, k, s, tile_k, block_n=BLOCK_N))
 
 
 dispatch.register_lint("segment_aggregate", _lowering_case)

@@ -4,10 +4,11 @@ import jax.numpy as jnp
 
 
 def segment_aggregate_ref(keys, slots, vals, acc):
-    # out-of-range keys (either side) are dead lanes, exactly as the Pallas
-    # kernel's in_tile mask drops them — the backends must never diverge.
-    k = acc.shape[0]
-    ok = (keys >= 0) & (keys < k)
+    # out-of-range keys or slots (either side) are dead lanes, exactly as
+    # the Pallas kernel drops them — the backends must never diverge (a
+    # negative slot would otherwise wrap to the last slot).
+    k, s = acc.shape[:2]
+    ok = (keys >= 0) & (keys < k) & (slots >= 0) & (slots < s)
     safe_k = jnp.clip(keys, 0, k - 1)
     upd = jnp.where(ok[:, None], vals, 0.0)
     return acc.at[safe_k, slots].add(upd, mode="drop")

@@ -8,14 +8,16 @@ primitives Mosaic cannot lower, VMEM overruns) — at no chip time.  The
 Pallas interpreter the rest of the suite uses accepts all of those.
 
 Each case compiles one kernel at the per-block shapes of the deployment
-that runs it (``chip_smoke.py``'s q1 wordcount for the merges and
-``segment_aggregate``; the q3 join; qwen3-14b and rwkv6-7b head widths),
-with a short grid.  The topology is described inside a fixture, never at
-import: only one process may load the TPU library, and pytest-xdist
-workers each import every test file.
+that runs it (``chip_smoke.py``'s q1 wordcount for the merges; the q3
+join; qwen3-14b and rwkv6-7b head widths), with a short grid.
+``segment_aggregate`` compiles at the whole call of the benchmark's q1
+deployment, its shapes read from the traced pipeline step.  The topology
+is described inside a fixture, never at import: only one process may load
+the TPU library, and pytest-xdist workers each import every test file.
 """
 
 import functools
+import json
 import os
 
 import jax
@@ -32,10 +34,64 @@ from repro.kernels.segment_aggregate.segment_aggregate import \
 from repro.kernels.window_join.window_join import window_join
 
 F32, I32 = jnp.float32, jnp.int32
+Q1_CONFIG = os.path.join(os.path.dirname(__file__), os.pardir, "perfbench",
+                         "configs", "q1-wordcount.json")
+
+
+def _q1_segment_aggregate_shapes():
+    """The (keys, slots, vals, acc) shapes of the ``segment_aggregate``
+    call in the benchmark's q1 step: the pipeline the configuration
+    builds, traced over a super-batch of the device root merge's output,
+    and the jitted call that wraps the kernel read off its jaxpr."""
+    from repro.api import RuntimeConfig, make_pipeline
+    from repro.core import tuples as T
+    from repro.core.runtime import _pad_stack
+    from repro.ingest.root import RootMerge
+    from repro.kernels.lowering import _as_jaxpr, _sub_jaxprs
+
+    with open(Q1_CONFIG) as f:
+        d = json.load(f)
+    cfg = RuntimeConfig.from_json({**d, "backend": "pallas"})
+    kmax, width = d["words_per_tweet"], 1
+    leaves = cfg.effective_max_leaves
+    root = RootMerge(leaves, cfg.root_cap, kmax, width,
+                     range(cfg.ingest_hosts), out_pad=cfg.out_pad,
+                     device=True)
+    rows = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((cfg.ingest_hosts,) + a.shape,
+                                       a.dtype),
+        T.empty_batch(root.chunk, kmax, width))
+    _, tick = jax.eval_shape(root._push_stacked, root.state, rows,
+                             jnp.zeros((leaves,), I32),
+                             jnp.zeros((leaves,), bool))
+    pipe = make_pipeline(cfg)
+    pipe.ensure_gate_for(kmax, width)
+    ctrl = T.empty_batch(pipe.op.n_inputs, kmax, width)
+    stack = jax.eval_shape(_pad_stack, ctrl, *[tick] * cfg.super_batch)
+    step = jax.make_jaxpr(pipe._persistent_fn)(
+        pipe.sg, pipe.epoch, pipe.sigma, stack, ctrl, jnp.zeros((), I32),
+        pipe.epoch.fmu, pipe.epoch.active)
+
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            inner = _as_jaxpr(eqn.params.get("jaxpr"))
+            if inner is not None and any(
+                    e.primitive.name == "pallas_call"
+                    and e.params.get("name") == "segment_aggregate"
+                    for e in inner.eqns):
+                yield [(v.aval.shape, v.aval.dtype) for v in eqn.invars]
+        for sub in _sub_jaxprs(jaxpr):
+            yield from calls(sub)
+
+    found = list(calls(step.jaxpr))
+    assert found, "the q1 step calls no segment_aggregate kernel"
+    assert all(c == found[0] for c in found), found
+    return found[0]
 
 
 def _cases():
-    """name -> (kernel entry, [(shape, dtype), ...])."""
+    """name -> (kernel entry, [(shape, dtype), ...] or a function that
+    resolves them)."""
     return {
         # q1 pipeline tick: 1024 stash + 3 x 8192 root rows + 4 ctrl lanes,
         # padded by the kernel to 32768 lanes
@@ -46,11 +102,11 @@ def _cases():
         "scalegate_merge_stacked": (
             scalegate_merge_stacked,
             [((3, 8192), I32)] * 3 + [((6,), I32)]),
-        # q1 wordcount blocks (128 keys x 4 slots x 512 hits), 8 x 4 grid
+        # the benchmark's q1 call, from its step (shapes resolved in the
+        # test): 65,536 keys x 5 slots, 25,604 lanes x 6 keys x 3 windows
         "segment_aggregate": (
             functools.partial(segment_aggregate, tile_k=128),
-            [((2048,), I32), ((2048,), I32), ((2048, 1), F32),
-             ((1024, 4, 1), F32)]),
+            _q1_segment_aggregate_shapes),
         # q3 ScaleJoin: 256-tuple ticks, ring 32, 4 payload attributes
         "window_join": (
             functools.partial(window_join, ws=500, band=10.0, n_attrs=2,
@@ -104,6 +160,8 @@ def no_persistent_cache():
 @pytest.mark.parametrize("name", sorted(_cases()))
 def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
     fn, shapes = _cases()[name]
+    if callable(shapes):
+        shapes = shapes()
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()

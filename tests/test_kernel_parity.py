@@ -5,6 +5,8 @@ silently diverge from the ref oracle.  The *heavy* interpret-mode shape
 sweeps live in test_kernels.py behind ``@pytest.mark.slow``.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -105,6 +107,122 @@ def test_segment_aggregate_out_of_range_keys_dropped_on_both_backends():
     b = segment_aggregate_op(keys, slots, vals, acc, backend="xla")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b))
     assert float(np.asarray(b).sum()) == 2.0            # only keys 0 and 7
+
+
+# tiles of 64 keys x 3 slots; hit blocks of 512 lanes
+K_SEG, S_SEG, TILE_SEG = 512, 3, 64
+SEG_CASES = ["skewed", "all_dead", "one_tile", "every_tile",
+             "tile_boundary_in_block", "ragged_n", "slot_out_of_range"]
+
+
+def _segment_case(case: str, w: int):
+    """(keys, slots, vals, acc) of one grouped-schedule parity case."""
+    rng = np.random.default_rng(10 * SEG_CASES.index(case) + w)
+    n = 1000 if case == "ragged_n" else 1536
+    keys = rng.integers(0, K_SEG, n)
+    slots = rng.integers(0, S_SEG, n)
+    if case == "skewed":            # a few hot cells take most hits
+        keys = (rng.zipf(1.6, n) - 1) % 8 * 67
+        slots = rng.integers(0, 2, n)
+    elif case == "all_dead":        # out of range on either side
+        keys = rng.choice([-1, -7, K_SEG, K_SEG + 9], n)
+    elif case == "one_tile":        # three hit blocks, all in tile 2
+        keys = rng.integers(2 * TILE_SEG, 3 * TILE_SEG, n)
+    elif case == "tile_boundary_in_block":
+        # 300 hits of tile 0, then tile 1: its hits share block 0
+        keys = np.where(np.arange(n) < 300, rng.integers(0, TILE_SEG, n),
+                        rng.integers(TILE_SEG, 2 * TILE_SEG, n))
+    elif case == "slot_out_of_range":
+        slots = rng.integers(-2, S_SEG + 2, n)
+    vals = rng.integers(0, 4, (n, w))
+    acc = rng.integers(0, 5, (K_SEG, S_SEG, w))
+    return (keys.astype(np.int32), slots.astype(np.int32),
+            vals.astype(np.float32), acc.astype(np.float32))
+
+
+@pytest.mark.parametrize("w", [1, 3])
+@pytest.mark.parametrize("case", SEG_CASES)
+def test_segment_aggregate_grouped_parity(case, w):
+    """The grouped schedule reduces integer values bit-identically to the
+    xla scatter-add, and a hit whose key or slot is out of range is
+    dropped on both backends."""
+    keys, slots, vals, acc = _segment_case(case, w)
+    a = np.asarray(segment_aggregate_op(keys, slots, vals, acc,
+                                        tile_k=TILE_SEG,
+                                        backend="pallas-interpret"))
+    b = np.asarray(segment_aggregate_op(keys, slots, vals, acc,
+                                        backend="xla"))
+    np.testing.assert_array_equal(a, b)
+    live = ((keys >= 0) & (keys < K_SEG) & (slots >= 0)
+            & (slots < S_SEG))
+    assert a.sum() == acc.sum() + vals[live].sum()
+
+
+@pytest.mark.parametrize("n,k,tile_k", [(16, 32, 32), (1000, 512, 64),
+                                        (1536, 512, 64), (460872, 65536,
+                                                          128)])
+def test_segment_aggregate_grid_steps(n, k, tile_k):
+    """The visit grid grows with tiles plus blocks, not their product, and
+    is the grid the kernel builds."""
+    import jax
+    from repro.kernels.segment_aggregate.segment_aggregate import (
+        grid_steps, segment_aggregate)
+
+    visits, dense = grid_steps(n, k, tile_k)
+    n_tiles, n_blocks = k // tile_k, -(-n // 512)
+    assert dense == n_tiles * n_blocks
+    assert visits <= n_tiles + n_blocks
+    traced = jax.make_jaxpr(functools.partial(segment_aggregate,
+                                              tile_k=tile_k))(
+        np.zeros(n, np.int32), np.zeros(n, np.int32),
+        np.zeros((n, 1), np.float32), np.zeros((k, 2, 1), np.float32))
+    grids = [e.params["grid_mapping"].grid for e in traced.jaxpr.eqns
+             if e.primitive.name == "pallas_call"]
+    assert grids == [(visits,)]
+
+
+def test_segment_aggregate_schedule_visits_the_hit_pairs():
+    """Real visits are the (tile, block) pairs that hold hits, tile-major,
+    plus one for each tile without any; the rest repeat the last pair."""
+    import jax.numpy as jnp
+    from repro.kernels.segment_aggregate.segment_aggregate import _schedule
+
+    block, n_blocks, n_tiles = 4, 5, 6
+    sizes = np.asarray([3, 0, 6, 0, 0, 9])          # 18 sorted hits
+    offsets = np.concatenate([[0], np.cumsum(sizes)])
+    visits = n_tiles + n_blocks - 1
+    tiles, blocks, n_real = (np.asarray(a) for a in _schedule(
+        jnp.asarray(offsets, jnp.int32), block, n_blocks, visits))
+    want = []
+    for t in range(n_tiles):
+        lo, hi = offsets[t], offsets[t + 1]
+        span = range(lo // block, (hi - 1) // block + 1) if hi > lo else \
+            [min(lo // block, n_blocks - 1)]
+        want += [(t, b) for b in span]
+    assert int(n_real[0]) == len(want) <= visits
+    got = list(zip(tiles.tolist(), blocks.tolist()))
+    assert got[:len(want)] == want
+    assert got[len(want):] == [want[-1]] * (visits - len(want))
+
+
+def test_segment_aggregate_gauges_set_when_traced():
+    import jax
+    from repro import obs
+    from repro.kernels.segment_aggregate.segment_aggregate import (
+        grid_steps, segment_aggregate)
+
+    prev = obs.get()
+    o = obs.install(obs.ObsConfig(enabled=True))
+    try:
+        jax.make_jaxpr(segment_aggregate)(
+            np.zeros(1000, np.int32), np.zeros(1000, np.int32),
+            np.zeros((1000, 1), np.float32),
+            np.zeros((512, 3, 1), np.float32))
+    finally:
+        obs.set_current(prev)
+    visits, dense = grid_steps(1000, 512, 128)
+    assert o.registry.gauge("segment_aggregate.visits").value == visits
+    assert o.registry.gauge("segment_aggregate.dense_steps").value == dense
 
 
 def test_window_join_parity():
