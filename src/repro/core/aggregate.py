@@ -43,7 +43,12 @@ def reduce_aggregate(window: WindowSpec, k_virt: int, *, width: int = 1,
     """A/A+ with an incremental reducer f_R and expiry output f_A.
 
     zeta: {"acc": f32[K, slots, width]}; f_O emits ``[key, acc...]``.
+    Output payloads are float32, whose integers are exact only up to
+    2^24: over a larger key space f_O emits ``[key - lo, acc..., lo]``
+    with ``lo = key % 256``, each part exact, and the key is their sum.
     """
+    split = emit_key and k_virt > 2**24
+    p_out = width + int(emit_key) + int(split)
 
     def init_zeta():
         slots = window.n_slots + extra_slots
@@ -53,15 +58,17 @@ def reduce_aggregate(window: WindowSpec, k_virt: int, *, width: int = 1,
         acc = f_r(zeta_s["acc"], tup.payload)          # [K, width]
         k = zeta_s["acc"].shape[0]
         return ({"acc": acc},
-                jnp.zeros((k, width + 1), jnp.float32),
+                jnp.zeros((k, p_out), jnp.float32),
                 jnp.zeros((k,), bool))
 
     def f_o(zeta_s, win_l, key_ids):
+        payload = zeta_s["acc"]
         if emit_key:
+            lo = key_ids % 256 if split else jnp.zeros_like(key_ids)
             payload = jnp.concatenate(
-                [key_ids[:, None].astype(jnp.float32), zeta_s["acc"]], axis=-1)
-        else:
-            payload = zeta_s["acc"]
+                [(key_ids - lo)[:, None].astype(jnp.float32), payload]
+                + ([lo[:, None].astype(jnp.float32)] if split else []),
+                axis=-1)
         return payload, jnp.ones((key_ids.shape[0],), bool)
 
     def f_s(zeta_s, new_left):
@@ -70,7 +77,7 @@ def reduce_aggregate(window: WindowSpec, k_virt: int, *, width: int = 1,
                 jnp.zeros((k,), bool))
 
     return OperatorDef(window=window, n_inputs=n_inputs, k_virt=k_virt,
-                       payload_out=width + (1 if emit_key else 0),
+                       payload_out=p_out,
                        init_zeta=init_zeta, f_u=f_u, f_o=f_o, f_s=f_s,
                        out_cap=out_cap, extra_slots=extra_slots, name=name)
 
@@ -221,8 +228,18 @@ def tick_fast(op: OperatorDef, kind: str, st: FastAggState,
                                ops.next_l))
     span = latest - ops.next_l + 1
     coll = jnp.maximum(span - op.slots, 0) * any_live.astype(jnp.int32)
-    occ = ops.occupied
-    occ = occ.at[k_idx, s_idx].max(m_idx, mode="drop")
+    if kind == "count":
+        # a count cell is occupied exactly when its count is positive:
+        # each live hit adds one, and a window's close resets its slot to
+        # zero.  So the close reads occupancy off the counts, and no
+        # per-tick scatter of the hits into a (key, slot) mask is needed.
+        occ = ops.occupied
+
+        def occupancy(st_, s):
+            return jnp.any(st_.zeta["acc"][:, s] > 0, -1)
+    else:
+        occ = ops.occupied.at[k_idx, s_idx].max(m_idx, mode="drop")
+        occupancy = None
     # slot_l tracks which window generation owns each ring slot — a global
     # property of the window grid, so the update mask ignores keys, resp
     # and the local block entirely (m_any = lane-in-range only): every
@@ -234,7 +251,7 @@ def tick_fast(op: OperatorDef, kind: str, st: FastAggState,
     ops = dataclasses.replace(ops, zeta={"acc": acc}, occupied=occ,
                               watermark=w_end)
     outs = _empty_outputs(op.out_cap, op.payload_out)
-    ops, outs = _expire_all(op, ops, outs, w_end, resp,
-                            key_offset + jnp.arange(op.k_virt))
+    ops, outs = _expire_all(op, ops, outs, w_end, resp, key_offset,
+                            occupancy)
     return (FastAggState(op_state=ops, slot_l=slot_l,
                          collisions=coll), outs)

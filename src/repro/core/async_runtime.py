@@ -4,8 +4,8 @@ The batch drivers (benchmarks, tests) pre-stage whole streams and pay a
 host round-trip per tick.  This runtime makes the stream *live*:
 
 * an **ingest thread** pulls ticks from an ``io`` source, computes the tiny
-  host-side tick metadata (per-source frontier, tuple count, key
-  histogram), and ``stage``s the batch onto the device — so the
+  host-side tick metadata (per-source frontier, tuple count, the keys the
+  tick hits), and ``stage``s the batch onto the device — so the
   ``device_put`` of tick T+1 runs concurrently with device compute of
   tick T.  A ``BoundedQueue`` between the threads applies backpressure:
   the producer blocks, memory never grows past ``queue_cap`` ticks;
@@ -62,7 +62,7 @@ class TickMeta:
     tick_id: int
     n_tuples: int                  # valid data lanes
     frontier_before: np.ndarray    # i64[n_inputs] last tau per source BEFORE
-    key_hist: Optional[np.ndarray]  # i64[k_virt] (lane, key) routing counts
+    key_hits: Optional[np.ndarray]  # i32[hits] key of each (lane, key) hit
 
 
 @dataclasses.dataclass
@@ -157,28 +157,26 @@ def make_report(metrics: MetricsBus, reconfig_trace, switches: int,
         slo_breaches=[b.to_dict() for b in (slo_breaches or [])])
 
 
-def tick_meta(b: T.TupleBatch, tick_id: int, n_inputs: int, k_virt: int,
-              frontier: np.ndarray, with_hist: bool = True) -> TickMeta:
+def tick_meta(b: T.TupleBatch, tick_id: int, n_inputs: int,
+              frontier: np.ndarray, with_hits: bool = True) -> TickMeta:
     """Compute a tick's metadata and fold its taus into the running
     ``frontier`` (mutated) — numpy views only, no device work.
 
-    ``with_hist=False`` skips the O(B*KMAX) key histogram: it is only
-    consumed by the host-side load fallback for pipelines whose step does
-    not return a device ``inst_load`` (MeshPipeline), and the ingest
+    The key histogram of the tick is kept sparse, as the key of each
+    (valid lane, key) hit: O(B*KMAX) work and memory, whatever the key
+    space (span ``ingest.key_hist``).  ``with_hits=False`` skips it: it is
+    only consumed by the host-side load fallback for pipelines whose step
+    does not return a device ``inst_load`` (MeshPipeline), and the ingest
     thread should stay as light as possible."""
     ok = np.asarray(b.valid) & ~np.asarray(b.is_control)
     before = frontier.copy()
     fold_frontier(frontier, b, n_inputs)
-    hist = None
-    if with_hist:
-        keys = np.asarray(b.keys)
-        km = ok[:, None] & (keys >= 0)
-        if km.any():
-            hist = np.bincount(keys[km].ravel(),
-                               minlength=k_virt).astype(np.int64)
-        else:
-            hist = np.zeros((k_virt,), np.int64)
-    return TickMeta(tick_id, int(ok.sum()), before, hist)
+    hits = None
+    if with_hits:
+        with _obs.span("ingest.key_hist", tick=tick_id):
+            keys = np.asarray(b.keys)
+            hits = keys[ok[:, None] & (keys >= 0)]
+    return TickMeta(tick_id, int(ok.sum()), before, hits)
 
 
 class AsyncStreamRuntime:
@@ -232,23 +230,21 @@ class AsyncStreamRuntime:
     # -- ingest thread ------------------------------------------------------
     def _ingest(self, max_ticks: Optional[int]):
         n_inputs = self.pipeline.op.n_inputs
-        k_virt = self.pipeline.op.k_virt
-        # the key histogram is only needed for the host-side load fallback
-        # (pipelines whose step doesn't return a device inst_load)
-        with_hist = not getattr(self.pipeline, "device_inst_load", False)
+        # the tick's key hits are only needed for the host-side load
+        # fallback (pipelines whose step doesn't return a device inst_load)
+        with_hits = not getattr(self.pipeline, "device_inst_load", False)
         frontier = _initial_frontier(self.pipeline, n_inputs)
         try:
             if self.super_batch > 1:
-                self._ingest_super(max_ticks, n_inputs, k_virt, with_hist,
-                                   frontier)
+                self._ingest_super(max_ticks, n_inputs, with_hits, frontier)
             else:
                 for i, b in enumerate(self.source):
                     if max_ticks is not None and i >= max_ticks:
                         break
                     tick_id = self.tick0 + i
                     with _obs.span("ingest.stage", tick=tick_id):
-                        meta = tick_meta(b, tick_id, n_inputs, k_virt,
-                                         frontier, with_hist=with_hist)
+                        meta = tick_meta(b, tick_id, n_inputs, frontier,
+                                         with_hits=with_hits)
                         staged = self.pipeline.stage(b)   # async transfer
                     tl = _obs.exemplars()
                     if tl is not None:
@@ -263,8 +259,8 @@ class AsyncStreamRuntime:
         finally:
             self.queue.close()
 
-    def _ingest_super(self, max_ticks, n_inputs: int, k_virt: int,
-                      with_hist: bool, frontier: np.ndarray):
+    def _ingest_super(self, max_ticks, n_inputs: int, with_hits: bool,
+                      frontier: np.ndarray):
         """Group up to ``super_batch`` consecutive same-shape ticks and
         stage each group as one device stack.  A shape change flushes the
         open group early; a partial group is padded with all-invalid no-op
@@ -297,8 +293,8 @@ class AsyncStreamRuntime:
             if group and key != gkey:
                 flush()
             gkey = key
-            metas.append(tick_meta(b, self.tick0 + i, n_inputs, k_virt,
-                                   frontier, with_hist=with_hist))
+            metas.append(tick_meta(b, self.tick0 + i, n_inputs, frontier,
+                                   with_hits=with_hits))
             tl = _obs.exemplars()
             if tl is not None:
                 ok = np.asarray(b.valid) & ~np.asarray(b.is_control)
@@ -313,22 +309,24 @@ class AsyncStreamRuntime:
 
     @staticmethod
     def _combine_meta(metas: List[TickMeta]) -> TickMeta:
-        """One decision-granularity view of a super-batch: tuple counts and
-        key histograms sum; the frontier stamp is the one BEFORE the first
-        tick (the reconfiguration is injected there)."""
-        hist = (None if metas[0].key_hist is None
-                else np.sum([m.key_hist for m in metas], axis=0))
+        """One decision-granularity view of a super-batch: tuple counts sum
+        and key hits concatenate; the frontier stamp is the one BEFORE the
+        first tick (the reconfiguration is injected there)."""
+        hits = (None if metas[0].key_hits is None
+                else np.concatenate([m.key_hits for m in metas]))
         return TickMeta(tick_id=metas[0].tick_id,
                         n_tuples=sum(m.n_tuples for m in metas),
                         frontier_before=metas[0].frontier_before,
-                        key_hist=hist)
+                        key_hits=hits)
 
     # -- metric sampling ----------------------------------------------------
-    def _host_inst_load(self, key_hist) -> Optional[np.ndarray]:
-        if key_hist is None:
+    def _host_inst_load(self, key_hits) -> Optional[np.ndarray]:
+        """Per-instance load of the hits under the committed f_mu: one unit
+        per hit routed to its owner, O(hits) whatever the key space."""
+        if key_hits is None:
             return None
         n_max = self._active_shadow.shape[0]
-        return np.bincount(self._fmu_shadow, weights=key_hist,
+        return np.bincount(self._fmu_shadow[key_hits],
                            minlength=n_max).astype(np.int64)
 
     def _drain(self, pending, idle_s: float = 0.0):
@@ -341,7 +339,7 @@ class AsyncStreamRuntime:
         with _obs.span("runtime.drain", tick=tick_id):
             sw = bool(np.asarray(switched))
             load = (np.asarray(inst_load) if inst_load is not None
-                    else self._host_inst_load(meta.key_hist))
+                    else self._host_inst_load(meta.key_hits))
         latency = max(time.perf_counter() - t_dispatch - idle_s, 0.0)
         _obs.event("tick", tick_id=tick_id, n_tuples=meta.n_tuples,
                    latency_ms=latency * 1e3, queue_depth=self.queue.depth,
@@ -515,7 +513,6 @@ def run_sync(pipeline, source, sink=None, controller=None,
     sink = sink if sink is not None else CollectSink()
     metrics = MetricsBus(queue_cap=0)
     n_inputs = pipeline.op.n_inputs
-    k_virt = pipeline.op.k_virt
     frontier = _initial_frontier(pipeline, n_inputs)
     replay = dict(reconfig_trace) if reconfig_trace is not None else None
     trace: List[Tuple[int, Reconfiguration]] = []
@@ -525,8 +522,7 @@ def run_sync(pipeline, source, sink=None, controller=None,
     for tick_id, b in enumerate(source):
         if max_ticks is not None and tick_id >= max_ticks:
             break
-        meta = tick_meta(b, tick_id, n_inputs, k_virt, frontier,
-                         with_hist=False)
+        meta = tick_meta(b, tick_id, n_inputs, frontier, with_hits=False)
         if replay is not None:
             rc = replay.get(tick_id)
         elif controller is not None:

@@ -233,14 +233,14 @@ def tick_fast(window: WindowSpec, f_j: Callable, st: FastJoinState,
              jnp.broadcast_to(st.pay[None], (b, k_virt, ring, p))], axis=-1)
         tau1 = jnp.broadcast_to((ready.tau + window.wa)[:, None, None],
                                 (b, k_virt, ring))
-        outs = _emit(outs, tau1.reshape(-1),
-                     pay1.reshape(-1, 2 * p), hit1.reshape(-1))
+        outs = _emit(outs, tau1.reshape(-1), hit1.reshape(-1),
+                     lambda idx: pay1.reshape(-1, 2 * p)[idx])
         pay2 = jnp.concatenate(
             [jnp.broadcast_to(ready.payload[:, None, :], (b, b, p)),
              jnp.broadcast_to(ready.payload[None], (b, b, p))], axis=-1)
         tau2 = jnp.broadcast_to((ready.tau + window.wa)[:, None], (b, b))
-        outs = _emit(outs, tau2.reshape(-1),
-                     pay2.reshape(-1, 2 * p), hit2.reshape(-1))
+        outs = _emit(outs, tau2.reshape(-1), hit2.reshape(-1),
+                     lambda idx: pay2.reshape(-1, 2 * p)[idx])
 
     # --- phase 3: store (round-robin, one key per tuple) -------------------
     pos = jnp.mod(st.n[store_key] + 0, ring)

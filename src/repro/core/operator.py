@@ -91,22 +91,47 @@ def _empty_outputs(cap: int, p: int) -> Outputs:
                    overflow=jnp.zeros((), jnp.int32))
 
 
-def _emit(outs: Outputs, tau: jax.Array, payload: jax.Array,
-          valid: jax.Array) -> Outputs:
-    """Append up to K masked rows into the output buffer (drop + count extra)."""
-    cap = outs.tau.shape[0]
-    vi = valid.astype(jnp.int32)
-    pos = outs.count + jnp.cumsum(vi) - vi  # target lane per emitted row
-    idx = jnp.where(valid & (pos < cap), pos, cap)  # cap == drop lane
-    n = jnp.sum(vi)
-    tau_b = jnp.broadcast_to(jnp.asarray(tau, jnp.int32), valid.shape)
+EMIT_ROW = 128      # most rows counted together by ``_emit``'s first level
+
+
+def _emit(outs: Outputs, tau: jax.Array, valid: jax.Array,
+          rows: Callable[[jax.Array], jax.Array]) -> Outputs:
+    """Append the rows whose ``valid`` is set, in order, to the output
+    buffer (drop + count what does not fit).  ``tau`` is one time for every
+    row or one per row; ``rows(idx)`` gives the payload of the rows
+    ``idx``.
+
+    Each lane of the buffer finds the row it takes in two levels: the
+    valid rows are counted per group, a ``searchsorted`` on the running
+    count of the groups finds the lane's group, and a count inside that
+    one group finds the row.  A group holds ``rows offered // lanes``
+    rows, at most ``EMIT_ROW``, so the counts inside the lanes' groups
+    cost no more than one pass over ``valid``, and no running count as
+    long as ``valid`` is made when the rows far outnumber the lanes (a
+    window's close over a large key block)."""
+    cap, n_all = outs.tau.shape[0], valid.shape[0]
+    width = max(1, min(EMIT_ROW, n_all // cap))
+    n_groups = -(-n_all // width)
+    groups = jnp.pad(valid, (0, n_groups * width - n_all)
+                     ).reshape(n_groups, width)
+    ends = jnp.cumsum(jnp.sum(groups, axis=1, dtype=jnp.int32))
+    n = ends[-1]
+    j = jnp.arange(cap, dtype=jnp.int32) - outs.count  # new row of each lane
+    take = (j >= 0) & (j < n)
+    g = jnp.minimum(jnp.searchsorted(ends, j + 1, side="left"),
+                    n_groups - 1)
+    rank = j - jnp.where(g > 0, ends[g - 1], 0)      # among g's valid rows
+    inside = jnp.cumsum(groups[g], axis=1, dtype=jnp.int32)
+    idx = jnp.minimum(g * width + jnp.argmax(inside > rank[:, None],
+                                             axis=1), n_all - 1)
+    payload = rows(idx).astype(jnp.float32)
+    tau = jnp.asarray(tau, jnp.int32)
     return Outputs(
-        tau=outs.tau.at[idx].set(tau_b, mode="drop"),
-        payload=outs.payload.at[idx].set(payload.astype(jnp.float32), mode="drop"),
-        valid=outs.valid.at[idx].set(valid, mode="drop"),
+        tau=jnp.where(take, tau[idx] if tau.ndim else tau, outs.tau),
+        payload=jnp.where(take[:, None], payload, outs.payload),
+        valid=outs.valid | take,
         count=jnp.minimum(outs.count + n, cap),
-        overflow=outs.overflow + jnp.maximum(outs.count + n - cap, 0) -
-                 jnp.maximum(outs.count - cap, 0),
+        overflow=outs.overflow + jnp.maximum(outs.count + n - cap, 0),
     )
 
 
@@ -215,42 +240,62 @@ def _slice_slot(zeta, s):
 
 
 def _set_slot(zeta, s, zeta_s):
-    return jax.tree.map(lambda a, v: a.at[:, s].set(v), zeta, zeta_s)
+    """Write one slot in place (an update of the slot, not a scatter)."""
+    return jax.tree.map(
+        lambda a, v: jax.lax.dynamic_update_index_in_dim(
+            a, v.astype(a.dtype), s, 1), zeta, zeta_s)
 
 
 def _expire_round(op: OperatorDef, st: OpState, outs: Outputs,
-                  resp: jax.Array, key_ids: jax.Array):
+                  resp: jax.Array, key_offset,
+                  occupancy: Optional[Callable] = None):
     """forwardAndShift for the earliest live window generation (Alg. 2 L12-18).
 
     Emits f_O for every occupied+responsible key of the expiring generation,
-    then slides (WT=single) or recycles (WT=multi) the slot.
+    then slides (WT=single) or recycles (WT=multi) the slot.  Key ids are
+    ``key_offset + row``.  ``occupancy(st, s)`` gives the slot's occupied
+    keys where a fast path derives them from the state, which then keeps
+    no ``occupied`` mask (default: ``st.occupied[:, s]``).
+
+    The work over all rows is one pass over the slot: f_O's validity, the
+    occupancy and the count of the rows to emit.  f_O's payload is
+    computed only for the rows the output buffer takes, gathered from the
+    slot; f_O is pure, so it may run twice.
     """
     ws = op.window
     s = op.slot_of(st.next_l)
     zeta_s = _slice_slot(st.zeta, s)
-    payload, f_valid = op.f_o(zeta_s, st.next_l, key_ids)
-    occ = st.occupied[:, s]
+    _, f_valid = op.f_o(zeta_s, st.next_l,
+                        key_offset + jnp.arange(op.k_virt))
+    occ = (st.occupied[:, s] if occupancy is None
+           else occupancy(st, s))
     emit_mask = f_valid & occ & resp
-    outs = _emit(outs, ws.right_of(st.next_l), payload, emit_mask)
+
+    def rows(idx):
+        payload, _ = op.f_o(jax.tree.map(lambda a: a[idx], zeta_s),
+                            st.next_l, key_offset + idx)
+        return payload
+
+    outs = _emit(outs, ws.right_of(st.next_l), emit_mask, rows)
 
     if ws.wt == SINGLE:
         # slide the instance forward by WA; f_S purges / shifts state.
         zeta_new, still_occ = op.f_s(zeta_s, ws.left_of(st.next_l + 1))
         zeta = _set_slot(st.zeta, s, zeta_new)
-        occupied = st.occupied.at[:, s].set(still_occ & occ)
+        occupied = _set_slot(st.occupied, s, still_occ & occ)
     else:
         # recycle the slot for window generation next_l + n_slots.
-        blank = _slice_slot(jax.tree.map(jnp.zeros_like, st.zeta), s)
-        fresh = _slice_slot(op.init_zeta(), s)
-        del blank
-        zeta = _set_slot(st.zeta, s, fresh)
-        occupied = st.occupied.at[:, s].set(False)
+        zeta = _set_slot(st.zeta, s, _slice_slot(op.init_zeta(), s))
+        occupied = _set_slot(st.occupied, s, jnp.zeros_like(occ))
+    if occupancy is not None:
+        occupied = st.occupied        # no mask is kept beside the state
     return dataclasses.replace(st, zeta=zeta, occupied=occupied,
                                next_l=st.next_l + 1), outs
 
 
 def _expire_all(op: OperatorDef, st: OpState, outs: Outputs, w,
-                resp: jax.Array, key_ids: jax.Array):
+                resp: jax.Array, key_offset=0,
+                occupancy: Optional[Callable] = None):
     """while rho + WS <= W: forwardAndShift (Alg. 2 L33-35).
 
     NOTE the paper checks ``rho + WS < W`` with *exclusive* boundaries over
@@ -264,7 +309,7 @@ def _expire_all(op: OperatorDef, st: OpState, outs: Outputs, w,
 
     def body(carry):
         st, outs = carry
-        return _expire_round(op, st, outs, resp, key_ids)
+        return _expire_round(op, st, outs, resp, key_offset, occupancy)
 
     return jax.lax.while_loop(cond, body, (st, outs))
 
@@ -301,7 +346,7 @@ def process_tuple(op: OperatorDef, st: OpState, outs: Outputs, tup: Tup,
                            next_l)
         st = dataclasses.replace(st, next_l=next_l)
     else:
-        st, outs = _expire_all(op, st, outs, w, resp, key_ids)
+        st, outs = _expire_all(op, st, outs, w, resp, key_offset)
 
     # handleInputTuple (Alg. 2 L19-30).
     resp_tuple = resp  # bool[K] — f_mu(k) == j for this instance
@@ -338,7 +383,8 @@ def process_tuple(op: OperatorDef, st: OpState, outs: Outputs, tup: Tup,
             payload = payload.reshape(-1, payload.shape[-1])
         else:
             emit_valid = f_valid & mask
-        outs = _emit(outs, ws.right_of(l), payload, emit_valid)
+        outs = _emit(outs, ws.right_of(l), emit_valid,
+                     lambda idx: payload[idx])
         return dataclasses.replace(st, zeta=zeta, occupied=occupied), outs
 
     n_upd = ws.n_slots if ws.wt == MULTI else 1
@@ -384,6 +430,5 @@ def tick(op: OperatorDef, st: OpState, ready: T.TupleBatch,
             st = dataclasses.replace(
                 st, next_l=jnp.maximum(st.next_l, op.window.earliest_win_l(w)))
         else:
-            st, outs = _expire_all(op, st, outs, w, resp,
-                                   key_offset + jnp.arange(op.k_virt))
+            st, outs = _expire_all(op, st, outs, w, resp, key_offset)
     return st, outs

@@ -95,6 +95,14 @@ def _pad_stack(pad: T.TupleBatch, *batches: T.TupleBatch) -> T.TupleBatch:
     return jax.tree.map(lambda *xs: jnp.stack(xs), *padded)
 
 
+@jax.jit
+def _pick_tables(c_fmu, c_next, *tables):
+    """The epoch tables a mesh step's codes name (see ``MeshPipeline``)."""
+    def pick(c):
+        return jax.lax.switch(c, [lambda t=t: t for t in tables])
+    return pick(c_fmu), pick(c_next)
+
+
 @dataclasses.dataclass
 class PersistentOut:
     """Host-visible result of one persistent K-tick run.  The data lane
@@ -467,6 +475,14 @@ class MeshPipeline:
     and scanned inside one compiled shard_map call, so the hot loop does
     not round-trip to Python per tick.  ``step(b)`` is the T=1 view with
     the VSNPipeline return convention.
+
+    The step never reads ``f_mu`` (a shard computes the keys it stores), so
+    the ``[k_virt]`` epoch tables stay out of it: the step's epoch carries
+    codes in their place (0 = ``epoch.fmu``, 1 = ``epoch.fmu_next``, 2 =
+    the call's new table), which the epoch functions select exactly as
+    they would the tables.  Until a reconfiguration is first injected the
+    codes cannot change and the tables stay as they are; from then on each
+    call picks its tables by the codes it returns (``_pick_tables``).
     """
     op: OperatorDef
     mesh: Any
@@ -490,9 +506,6 @@ class MeshPipeline:
         self.n_max = self.n_max or self.n_shards
         self.n_active = self.n_active or self.n_max
         k = self.op.k_virt
-        fmu = jnp.asarray(np.arange(k) % self.n_active, jnp.int32)
-        active = jnp.asarray(np.arange(self.n_max) < self.n_active, bool)
-        self.epoch = elastic.init_epoch(fmu, active)
         if self.mode == "general":
             if self.op.lazy_expiry:
                 # lazy-expiry operators (ScaleJoin) purge/store inside f_U
@@ -502,22 +515,39 @@ class MeshPipeline:
                     "MeshPipeline mode='general' does not support "
                     "lazy-expiry operators (ScaleJoin): use "
                     "vsn.shard_tick with vsn.join_local_tick")
-            sigma = self.op.init_state()
+            init_sigma = self.op.init_state
             make_local = vsn.general_local_tick(self.op)
         elif self.mode == "fast-agg":
             from repro.core.aggregate import fast_init
-            sigma = fast_init(self.op)
+            init_sigma = functools.partial(fast_init, self.op)
             make_local = vsn.fast_agg_local_tick(self.op, self.agg_kind,
                                                  self.backend)
         else:
             raise ValueError(f"unknown mode {self.mode!r}")
-        self.sigma = vsn.mesh_device_put(sigma, self.mesh, self.axis, k)
+        # every table is made where it lives: each device builds its own
+        # key block of sigma and its replica of the epoch tables, so no
+        # device ever holds the whole state
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        sigma = jax.eval_shape(init_sigma)
+        self.sigma = jax.jit(init_sigma, out_shardings=jax.tree.map(
+            lambda sp: NamedSharding(self.mesh, sp),
+            vsn.mesh_state_spec(sigma, k, self.axis)))()
+        rep = NamedSharding(self.mesh, P())
+        n_active = self.n_active
+        fmu = jax.jit(lambda: jnp.arange(k, dtype=jnp.int32) % n_active,
+                      out_shardings=rep)()
+        self.epoch = elastic.init_epoch(fmu, jax.device_put(
+            np.arange(self.n_max) < n_active, rep))
+        self._codes = [jax.device_put(np.int32(c), rep) for c in range(3)]
+        self._tables_move = False
+        from repro import obs as _obs
+        _obs.gauge_set("mesh.shard_keys", k // self.n_shards)
         self._step_fn = vsn.shard_pipeline_step(self.op, self.mesh, self.axis,
                                                 make_local, sigma)
         self._jit = jax.jit(self._step_fn)   # one jit; it caches per shape
         # persistent variant: ctrl injection fused into the compiled call,
-        # sigma (the only big buffer; arg 2) donated.  sg/epoch are small
-        # replicated tables and stay undonated (fmu_new may alias epoch).
+        # sigma (the only big buffer; arg 2) donated.  sg and the coded
+        # epoch are small replicated state and stay undonated.
         self._persistent = jax.jit(self._persistent_fn, donate_argnums=(2,))
         self._persistent_structs = {}
         self.last_wmarks = None              # i32[T] of the latest run
@@ -567,6 +597,8 @@ class MeshPipeline:
         self.sigma = vsn.mesh_device_put(host_sigma, self.mesh, self.axis,
                                          self.op.k_virt)
         self._sg_ready = True
+        # a snapshot may hold a pending switch: its codes can move from here
+        self._tables_move = True
 
     def _frontier_after(self, batches, frontier0=None):
         """Per-source last forwarded tau once ``batches`` have been pushed:
@@ -578,6 +610,25 @@ class MeshPipeline:
         for b in batches:
             fold_frontier(frontier, b, self.op.n_inputs)
         return frontier
+
+    def _epoch_args(self, reconfig: Optional[Reconfiguration]):
+        """The step's (coded epoch, new-table code, new active set), and the
+        call's new table."""
+        coded = dataclasses.replace(self.epoch, fmu=self._codes[0],
+                                    fmu_next=self._codes[1])
+        if reconfig is None:
+            return coded, self._codes[0], self.epoch.active, self.epoch.fmu
+        self._tables_move = True
+        return (coded, self._codes[2], jnp.asarray(reconfig.active),
+                jnp.asarray(reconfig.fmu))
+
+    def _settle(self, coded, new_table):
+        """Put the tables the step's codes name back into the epoch."""
+        fmu, fmu_next = self.epoch.fmu, self.epoch.fmu_next
+        if self._tables_move:
+            fmu, fmu_next = _pick_tables(coded.fmu, coded.fmu_next, fmu,
+                                         fmu_next, new_table)
+        self.epoch = dataclasses.replace(coded, fmu=fmu, fmu_next=fmu_next)
 
     # -- the driver --------------------------------------------------------
     def stage(self, incoming: T.TupleBatch) -> T.TupleBatch:
@@ -627,16 +678,9 @@ class MeshPipeline:
             padded.append(T.concat(b, pad))
         inc_stack = jax.tree.map(lambda *xs: jnp.stack(xs), *padded)
 
-        if reconfig is not None:
-            fmu_new = jnp.asarray(reconfig.fmu)
-            active_new = jnp.asarray(reconfig.active)
-        else:
-            fmu_new = self.epoch.fmu
-            active_new = self.epoch.active
-
+        coded, fmu_new, active_new, new_table = self._epoch_args(reconfig)
         key = (len(padded), padded[0].batch, kmax, p)
-        args = (self.sg, self.epoch, self.sigma, inc_stack, fmu_new,
-                active_new)
+        args = (self.sg, coded, self.sigma, inc_stack, fmu_new, active_new)
         # re-captured every call so collective_bytes lowers the steady-state
         # variant (first-call inputs arrive host-placed, later ones carry
         # the replicated shardings of the previous step's outputs).  Only
@@ -652,8 +696,9 @@ class MeshPipeline:
                 sharding=sh if isinstance(sh, NamedSharding) else None)
 
         self._arg_structs[key] = jax.tree.map(struct, args)
-        (self.sg, self.epoch, self.sigma, outs1, outs2, switched,
+        (self.sg, coded, self.sigma, outs1, outs2, switched,
          wmk) = self._jit(*args)
+        self._settle(coded, new_table)
         self.last_wmarks = wmk
         return outs1, outs2, switched
 
@@ -697,14 +742,11 @@ class MeshPipeline:
             ctrl = ctrl_lanes(self.op.n_inputs, frontier, reconfig.epoch,
                               kmax, p)
             rc = jnp.asarray(max(reconfig_at, 0), jnp.int32)
-            fmu_new = jnp.asarray(reconfig.fmu)
-            active_new = jnp.asarray(reconfig.active)
         else:
             ctrl = T.empty_batch(self.op.n_inputs, kmax, p)
             rc = jnp.zeros((), jnp.int32)
-            fmu_new = self.epoch.fmu
-            active_new = self.epoch.active
-        args = (self.sg, self.epoch, self.sigma, stack, ctrl, rc, fmu_new,
+        coded, fmu_new, active_new, new_table = self._epoch_args(reconfig)
+        args = (self.sg, coded, self.sigma, stack, ctrl, rc, fmu_new,
                 active_new)
 
         def struct(a):
@@ -715,8 +757,9 @@ class MeshPipeline:
 
         key = (stack.tau.shape[0], stack.tau.shape[1], kmax, p)
         self._persistent_structs[key] = jax.tree.map(struct, args)
-        (self.sg, self.epoch, self.sigma, o1, o2, sw,
+        (self.sg, coded, self.sigma, o1, o2, sw,
          wmk) = self._persistent(*args)
+        self._settle(coded, new_table)
         self.last_wmarks = wmk
         return PersistentOut(outs_pre=o1, outs_post=o2, switched=sw,
                              wmark=wmk, inst_load=None)

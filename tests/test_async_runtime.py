@@ -161,10 +161,56 @@ def test_per_instance_load_exposed():
     assert (load[4:] == 0).all()
 
     # the host-side fallback (mesh path) agrees with the device count
-    meta = tick_meta(b, 0, 1, K, np.zeros((1,), np.int64))
+    meta = tick_meta(b, 0, 1, np.zeros((1,), np.int64))
     fmu = np.asarray(pipe.epoch.fmu)
-    host_load = np.bincount(fmu, weights=meta.key_hist, minlength=8)
+    host_load = np.bincount(fmu[meta.key_hits], minlength=8)
     np.testing.assert_array_equal(host_load, load)
+
+
+def test_host_load_fallback_follows_the_hits():
+    """The host load fallback counts each tick's (lane, key) hits under the
+    committed f_mu: the same per-instance load as the dense key histogram
+    over all of K, before and after a reconfiguration.  Its span
+    ``ingest.key_hist`` is written once per tick, with the tick's id,
+    while tracing is on, and not at all while it is off."""
+    from repro import obs
+    from repro.core.controller import Reconfiguration
+
+    batches = agg_stream(n_ticks=4, seed=3)
+    rt = AsyncStreamRuntime(agg_pipe(n_active=4), ReplaySource(batches))
+    after = Reconfiguration(epoch=1, n_active=8,
+                            fmu=(np.arange(K) * 5 % 8).astype(np.int32),
+                            active=np.ones(8, bool))
+    prev = obs.set_current(None)
+    try:
+        o = obs.install(obs.ObsConfig(enabled=True, trace=True))
+        frontier = np.zeros((1,), np.int64)
+        metas = [tick_meta(b, 10 + i, 1, frontier)
+                 for i, b in enumerate(batches)]
+        recs = [r for r in o.tracer.finished if r["name"] == "ingest.key_hist"]
+        assert [r["ids"] for r in recs] == [{"tick": 10 + i}
+                                            for i in range(len(batches))]
+        obs.set_current(None)
+        tick_meta(batches[0], 0, 1, np.zeros((1,), np.int64))
+        assert len([r for r in o.tracer.finished
+                    if r["name"] == "ingest.key_hist"]) == len(batches)
+    finally:
+        obs.set_current(prev)
+    for fmu in (np.asarray(rt.pipeline.epoch.fmu), after.fmu):
+        rt._fmu_shadow = fmu
+        for b, meta in zip(batches, metas):
+            keys = np.asarray(b.keys)
+            ok = np.asarray(b.valid) & ~np.asarray(b.is_control)
+            dense = np.bincount(keys[ok[:, None] & (keys >= 0)],
+                                minlength=K)
+            want = np.bincount(fmu, weights=dense, minlength=8)
+            got = rt._host_inst_load(meta.key_hits)
+            np.testing.assert_array_equal(got, want)
+            assert got.sum() == dense.sum() > 0
+        combined = rt._combine_meta(metas)
+        np.testing.assert_array_equal(
+            rt._host_inst_load(combined.key_hits),
+            sum(rt._host_inst_load(m.key_hits) for m in metas))
 
 
 def test_snapshot_pairs_load_with_observed_active():

@@ -11,7 +11,10 @@ Each case compiles one kernel at the per-block shapes of the deployment
 that runs it (``chip_smoke.py``'s q1 wordcount for the merges; the q3
 join; qwen3-14b and rwkv6-7b head widths), with a short grid.
 ``segment_aggregate`` compiles at the whole call of the benchmark's q1
-deployment, its shapes read from the traced pipeline step.  The topology
+deployment, its shapes read from the traced pipeline step, and at one
+key block's call of the q1 paircount deployment sharded over four chips
+(its hit-tile schedule at 2^27 keys), read from the traced tick of one
+shard.  The topology
 is described inside a fixture, never at import: only one process may load
 the TPU library, and pytest-xdist workers each import every test file.
 """
@@ -34,25 +37,17 @@ from repro.kernels.segment_aggregate.segment_aggregate import \
 from repro.kernels.window_join.window_join import window_join
 
 F32, I32 = jnp.float32, jnp.int32
-Q1_CONFIG = os.path.join(os.path.dirname(__file__), os.pardir, "perfbench",
-                         "configs", "q1-wordcount.json")
+CONFIGS = os.path.join(os.path.dirname(__file__), os.pardir, "perfbench",
+                       "configs")
+Q1_CONFIG = os.path.join(CONFIGS, "q1-wordcount.json")
+PAIRCOUNT_CONFIG = os.path.join(CONFIGS, "q1-paircount-mesh4.json")
 
 
-def _q1_segment_aggregate_shapes():
-    """The (keys, slots, vals, acc) shapes of the ``segment_aggregate``
-    call in the benchmark's q1 step: the pipeline the configuration
-    builds, traced over a super-batch of the device root merge's output,
-    and the jitted call that wraps the kernel read off its jaxpr."""
-    from repro.api import RuntimeConfig, make_pipeline
+def _root_tick(cfg, kmax, width):
+    """Shape of one tick out of the device root merge of ``cfg``."""
     from repro.core import tuples as T
-    from repro.core.runtime import _pad_stack
     from repro.ingest.root import RootMerge
-    from repro.kernels.lowering import _as_jaxpr, _sub_jaxprs
 
-    with open(Q1_CONFIG) as f:
-        d = json.load(f)
-    cfg = RuntimeConfig.from_json({**d, "backend": "pallas"})
-    kmax, width = d["words_per_tweet"], 1
     leaves = cfg.effective_max_leaves
     root = RootMerge(leaves, cfg.root_cap, kmax, width,
                      range(cfg.ingest_hosts), out_pad=cfg.out_pad,
@@ -64,6 +59,61 @@ def _q1_segment_aggregate_shapes():
     _, tick = jax.eval_shape(root._push_stacked, root.state, rows,
                              jnp.zeros((leaves,), I32),
                              jnp.zeros((leaves,), bool))
+    return tick
+
+
+def _all_eqns(jaxpr):
+    from repro.kernels.lowering import _sub_jaxprs
+    yield from jaxpr.eqns
+    for sub in _sub_jaxprs(jaxpr):
+        yield from _all_eqns(sub)
+
+
+def _segment_aggregate_call(jaxpr):
+    """Operand shapes of the innermost jitted call that holds the kernel
+    (the wrapper; the kernel itself sits in its chunk loop), the same at
+    every call site."""
+    from repro.kernels.lowering import _as_jaxpr
+
+    def holds(j):
+        return any(e.primitive.name == "pallas_call"
+                   and e.params.get("name") == "segment_aggregate"
+                   for e in _all_eqns(j))
+
+    def calls(j):
+        for eqn in j.eqns:
+            subs = [_as_jaxpr(item) for val in eqn.params.values()
+                    for item in (val if isinstance(val, (list, tuple))
+                                 else [val])]
+            deeper = [c for sub in subs if sub is not None
+                      for c in calls(sub)]
+            inner = _as_jaxpr(eqn.params.get("jaxpr"))
+            if deeper:
+                yield from deeper
+            elif (inner is not None and eqn.primitive.name != "pallas_call"
+                  and holds(inner)):
+                yield [(v.aval.shape, v.aval.dtype) for v in eqn.invars]
+
+    found = list(calls(jaxpr))
+    assert found, "the step calls no segment_aggregate kernel"
+    assert all(c == found[0] for c in found), found
+    return found[0]
+
+
+def _q1_segment_aggregate_shapes():
+    """The (keys, slots, vals, acc) shapes of the ``segment_aggregate``
+    call in the benchmark's q1 step: the pipeline the configuration
+    builds, traced over a super-batch of the device root merge's output,
+    and the jitted call that wraps the kernel read off its jaxpr."""
+    from repro.api import RuntimeConfig, make_pipeline
+    from repro.core import tuples as T
+    from repro.core.runtime import _pad_stack
+
+    with open(Q1_CONFIG) as f:
+        d = json.load(f)
+    cfg = RuntimeConfig.from_json({**d, "backend": "pallas"})
+    kmax, width = d["words_per_tweet"], 1
+    tick = _root_tick(cfg, kmax, width)
     pipe = make_pipeline(cfg)
     pipe.ensure_gate_for(kmax, width)
     ctrl = T.empty_batch(pipe.op.n_inputs, kmax, width)
@@ -71,22 +121,46 @@ def _q1_segment_aggregate_shapes():
     step = jax.make_jaxpr(pipe._persistent_fn)(
         pipe.sg, pipe.epoch, pipe.sigma, stack, ctrl, jnp.zeros((), I32),
         pipe.epoch.fmu, pipe.epoch.active)
+    return _segment_aggregate_call(step.jaxpr)
 
-    def calls(jaxpr):
-        for eqn in jaxpr.eqns:
-            inner = _as_jaxpr(eqn.params.get("jaxpr"))
-            if inner is not None and any(
-                    e.primitive.name == "pallas_call"
-                    and e.params.get("name") == "segment_aggregate"
-                    for e in inner.eqns):
-                yield [(v.aval.shape, v.aval.dtype) for v in eqn.invars]
-        for sub in _sub_jaxprs(jaxpr):
-            yield from calls(sub)
 
-    found = list(calls(step.jaxpr))
-    assert found, "the q1 step calls no segment_aggregate kernel"
-    assert all(c == found[0] for c in found), found
-    return found[0]
+def _paircount_shard_segment_aggregate_shapes():
+    """The same for one key block of the q1 paircount deployment: the
+    pipeline tick one shard runs (``vsn.pipeline_tick`` over the shard's
+    local tick), traced on abstract state, since the deployment's state
+    does not fit this host."""
+    import dataclasses
+
+    from repro.api import RuntimeConfig, make_op
+    from repro.core import elastic, scalegate, vsn
+    from repro.core import tuples as T
+    from repro.core.aggregate import fast_init
+
+    with open(PAIRCOUNT_CONFIG) as f:
+        d = json.load(f)
+    cfg = RuntimeConfig.from_json({**d, "backend": "pallas"})
+    words, dist = d["words_per_tweet"], d["pair_dist"]
+    kmax, width = sum(min(dist, words - 1 - i) for i in range(words)), 1
+    assert kmax == 12
+    rows = cfg.k_virt // cfg.mesh_devices
+    op = make_op(cfg).resolved()
+    tick_l = vsn.fast_agg_local_tick(op, "count", "pallas")(0, rows)
+    op_l = vsn.localize_op(op, 0, rows)
+    sigma = jax.eval_shape(lambda: fast_init(op_l))
+    sg = scalegate.init_scalegate(op.n_inputs, cfg.stash_cap, kmax, width)
+    code = jnp.zeros((), I32)
+    epoch = dataclasses.replace(
+        elastic.init_epoch(code, jnp.ones((cfg.n_max,), bool)),
+        fmu_next=code)
+    incoming = jax.eval_shape(
+        T.concat, _root_tick(cfg, kmax, width),
+        T.empty_batch(op.n_inputs, kmax, width))
+    step = jax.make_jaxpr(lambda sigma, incoming: vsn.pipeline_tick(
+        sg, epoch, sigma, incoming, code, epoch.active,
+        lambda s, r, e: tick_l(s, r)))(sigma, incoming)
+    shapes = _segment_aggregate_call(step.jaxpr)
+    assert shapes[3][0] == (rows, 5, 1), shapes
+    return shapes
 
 
 def _cases():
@@ -107,6 +181,11 @@ def _cases():
         "segment_aggregate": (
             functools.partial(segment_aggregate, tile_k=128),
             _q1_segment_aggregate_shapes),
+        # one key block of q1 paircount on four chips: 2^27 keys x 5 slots,
+        # 25,604 lanes x 12 pair keys x 3 windows
+        "segment_aggregate_paircount_shard": (
+            functools.partial(segment_aggregate, tile_k=128),
+            _paircount_shard_segment_aggregate_shapes),
         # q3 ScaleJoin: 256-tuple ticks, ring 32, 4 payload attributes
         "window_join": (
             functools.partial(window_join, ws=500, band=10.0, n_attrs=2,

@@ -156,3 +156,42 @@ def test_output_timestamps_after_inputs_and_sorted():
     ts = [t for t, _ in all_out]
     assert ts == sorted(ts)
     assert min(ts) > int(taus.min())
+
+
+# ------------------------------------------------- output buffer append ---
+@pytest.mark.parametrize("n,cap,count", [(300, 64, 0), (300, 64, 50),
+                                         (1000, 512, 7), (129, 40, 0),
+                                         (5, 8, 8), (20000, 64, 3)])
+def test_emit_appends_valid_rows_in_order(n, cap, count):
+    """``_emit`` appends exactly the valid rows, in row order, after the
+    lanes already filled, drops and counts what does not fit, and leaves
+    the filled lanes alone: with groups of its two-level count of one row,
+    of a few, and of the most (``EMIT_ROW``), and with one time for all
+    rows or one per row."""
+    import dataclasses
+    from repro.core.operator import _emit, _empty_outputs
+
+    rng = np.random.default_rng(n + cap + count)
+    valid = rng.random(n) < 0.4
+    pay = rng.normal(size=(n, 2)).astype(np.float32)
+    taus = rng.integers(0, 100, n).astype(np.int32)
+    lane = np.arange(cap)
+    outs = dataclasses.replace(
+        _empty_outputs(cap, 2), count=jnp.int32(count),
+        valid=jnp.asarray(lane < count), tau=jnp.full((cap,), -5, jnp.int32),
+        payload=jnp.full((cap, 2), -7.0, jnp.float32))
+    rows = np.nonzero(valid)[0][:cap - count]
+    for tau in (taus, 42):
+        got = _emit(outs, jnp.asarray(tau), jnp.asarray(valid),
+                    lambda idx: jnp.asarray(pay)[idx])
+        want_tau = np.full(cap, -5)
+        want_tau[count:count + rows.size] = (taus[rows] if np.ndim(tau)
+                                             else tau)
+        want_pay = np.full((cap, 2), -7.0, np.float32)
+        want_pay[count:count + rows.size] = pay[rows]
+        np.testing.assert_array_equal(np.asarray(got.tau), want_tau)
+        np.testing.assert_array_equal(np.asarray(got.payload), want_pay)
+        np.testing.assert_array_equal(np.asarray(got.valid),
+                                      lane < count + rows.size)
+        assert int(got.count) == min(count + valid.sum(), cap)
+        assert int(got.overflow) == max(count + valid.sum() - cap, 0)

@@ -158,15 +158,31 @@ def test_segment_aggregate_grouped_parity(case, w):
     assert a.sum() == acc.sum() + vals[live].sum()
 
 
+def _pallas_grids(jaxpr):
+    """Grids of every ``pallas_call`` in a jaxpr, its loop bodies too;
+    each grid is as long as the call's schedule (its first operand)."""
+    from repro.kernels.lowering import _sub_jaxprs
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            grid = e.params["grid_mapping"].grid
+            assert grid == e.invars[0].aval.shape, (grid, e.invars[0].aval)
+            out.append(grid)
+    for sub in _sub_jaxprs(jaxpr):
+        out += _pallas_grids(sub)
+    return out
+
+
 @pytest.mark.parametrize("n,k,tile_k", [(16, 32, 32), (1000, 512, 64),
                                         (1536, 512, 64), (460872, 65536,
-                                                          128)])
+                                                          128),
+                                        (300, 65536, 128)])
 def test_segment_aggregate_grid_steps(n, k, tile_k):
     """The visit grid grows with tiles plus blocks, not their product, and
-    is the grid the kernel builds."""
+    is the grid the kernel builds (one call a chunk of visits)."""
     import jax
     from repro.kernels.segment_aggregate.segment_aggregate import (
-        grid_steps, segment_aggregate)
+        CHUNK, grid_steps, segment_aggregate)
 
     visits, dense = grid_steps(n, k, tile_k)
     n_tiles, n_blocks = k // tile_k, -(-n // 512)
@@ -176,33 +192,79 @@ def test_segment_aggregate_grid_steps(n, k, tile_k):
                                               tile_k=tile_k))(
         np.zeros(n, np.int32), np.zeros(n, np.int32),
         np.zeros((n, 1), np.float32), np.zeros((k, 2, 1), np.float32))
-    grids = [e.params["grid_mapping"].grid for e in traced.jaxpr.eqns
-             if e.primitive.name == "pallas_call"]
-    assert grids == [(visits,)]
+    n_pad = -(-n // 128) * 128 if n < 512 else n_blocks * 512
+    padded, _ = grid_steps(n_pad, k, tile_k)
+    assert visits <= padded <= visits + n_pad - n
+    assert _pallas_grids(traced.jaxpr) == [(min(padded, CHUNK),)]
 
 
 def test_segment_aggregate_schedule_visits_the_hit_pairs():
-    """Real visits are the (tile, block) pairs that hold hits, tile-major,
-    plus one for each tile without any; the rest repeat the last pair."""
+    """Real visits are the (tile, block) pairs that hold hits, tile-major;
+    a tile without hits has none, and the rest of a chunk repeats the
+    last pair."""
     import jax.numpy as jnp
-    from repro.kernels.segment_aggregate.segment_aggregate import _schedule
+    from repro.kernels.segment_aggregate.segment_aggregate import (
+        _chunk, _openings)
 
-    block, n_blocks, n_tiles = 4, 5, 6
+    block, n_tiles, s, tile_k = 4, 6, 2, 8
     sizes = np.asarray([3, 0, 6, 0, 0, 9])          # 18 sorted hits
+    cells = np.concatenate(
+        [np.full(z, t * tile_k * s + 1) for t, z in enumerate(sizes)]
+        + [np.full(2, n_tiles * tile_k * s)])       # 2 dead lanes
     offsets = np.concatenate([[0], np.cumsum(sizes)])
-    visits = n_tiles + n_blocks - 1
-    tiles, blocks, n_real = (np.asarray(a) for a in _schedule(
-        jnp.asarray(offsets, jnp.int32), block, n_blocks, visits))
+    tile, opened = _openings(jnp.asarray(cells, jnp.int32), tile_k * s,
+                             n_tiles, block)
+    n_real = int(opened[-1])
     want = []
     for t in range(n_tiles):
         lo, hi = offsets[t], offsets[t + 1]
-        span = range(lo // block, (hi - 1) // block + 1) if hi > lo else \
-            [min(lo // block, n_blocks - 1)]
-        want += [(t, b) for b in span]
-    assert int(n_real[0]) == len(want) <= visits
-    got = list(zip(tiles.tolist(), blocks.tolist()))
-    assert got[:len(want)] == want
-    assert got[len(want):] == [want[-1]] * (visits - len(want))
+        want += [(t, b) for b in range(lo // block, (hi - 1) // block + 1)
+                 if hi > lo]
+    assert n_real == len(want) == 7
+    chunk = 5
+    got = []
+    for c in range(2):
+        tiles, blocks, n_here = (np.asarray(a) for a in _chunk(
+            tile, opened, jnp.int32(n_real), c, block, chunk))
+        assert int(n_here[0]) == min(chunk, n_real - c * chunk)
+        got += list(zip(tiles.tolist(), blocks.tolist()))
+    assert got[:n_real] == want
+    assert got[n_real:] == [want[-1]] * (2 * chunk - n_real)
+
+
+def test_segment_aggregate_sparse_call_visits_only_hit_tiles():
+    """A few hundred hits in a handful of tiles of a 2^16-key accumulator:
+    bit-identical to the xla oracle, every untouched tile unchanged, and
+    no more real visits than hit tiles plus hit blocks."""
+    import jax.numpy as jnp
+    from repro.kernels.segment_aggregate.segment_aggregate import (
+        _openings, grid_steps)
+
+    k, s, tile_k, n = 2**16, 5, 128, 300
+    rng = np.random.default_rng(11)
+    hot = rng.choice(k // tile_k, 6, replace=False)
+    keys = (rng.choice(hot, n) * tile_k
+            + rng.integers(0, tile_k, n)).astype(np.int32)
+    slots = rng.integers(0, s, n).astype(np.int32)
+    vals = np.ones((n, 1), np.float32)
+    acc = rng.integers(0, 7, (k, s, 1)).astype(np.float32)
+    a = np.asarray(segment_aggregate_op(keys, slots, vals, acc,
+                                        tile_k=tile_k,
+                                        backend="pallas-interpret"))
+    b = np.asarray(segment_aggregate_op(keys, slots, vals, acc,
+                                        backend="xla"))
+    np.testing.assert_array_equal(a, b)
+    cold = np.ones(k // tile_k, bool)
+    cold[hot] = False
+    per_tile = lambda x: x.reshape(k // tile_k, tile_k * s)
+    np.testing.assert_array_equal(per_tile(a)[cold], per_tile(acc)[cold])
+    cells = np.sort(keys.astype(np.int64) * s + slots)
+    _, opened = _openings(jnp.asarray(cells, jnp.int32), tile_k * s,
+                          k // tile_k, 384)
+    hit_tiles = np.unique(keys // tile_k).size
+    hit_blocks = -(-n // 384)
+    assert int(opened[-1]) <= hit_tiles + hit_blocks
+    assert grid_steps(n, k, tile_k)[0] < (k // tile_k)
 
 
 def test_segment_aggregate_gauges_set_when_traced():
