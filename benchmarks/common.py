@@ -108,7 +108,6 @@ def run_device_resident_bench(make_stream, n_sources: int, n_leaves: int,
     exact-output gates (device-merged stream vs single-ScaleGate oracle,
     host-variant vs device-variant output multisets, device-variant vs a
     synchronous replay of its own merged stream)."""
-    from repro.core import tuples as T
     from repro.core.async_runtime import AsyncStreamRuntime
     from repro.ingest import IngestTier, collect_tuples, single_gate_stream
     from repro.ingest import leaf as L
@@ -169,10 +168,8 @@ def run_device_resident_bench(make_stream, n_sources: int, n_leaves: int,
         def flush():
             if not group:
                 return
-            b0 = group[0]
-            pad = [T.empty_batch(b0.batch, b0.kmax, b0.payload_width)
-                   ] * (super_batch - len(group))
-            out = pipe.run_persistent_staged(pipe.stage_super(group + pad))
+            out = pipe.run_persistent_staged(
+                pipe.stage_super(group, super_batch))
             bool(out.switched.any()), np.asarray(out.inst_load.sum(axis=0))
             fill[0] += 1
             fill[1] += len(group)

@@ -9,17 +9,18 @@ The deployment is the paper's q1 wordcount (§8.1,
 Zipf-1.3 tweets of 6 words over a 65,536-word vocabulary mapped to 65,536
 virtual keys, counted over sliding windows (WA = 1 s, WS = 2 s); 8,192
 tweets (49,152 key hits) per tick; 4 sources into 2 ingest leaves and the
-fused on-device root merge; the mesh pipeline with 8-tick persistent
-scans; 16 instances at most, scaled from 8 to 16 mid-stream.  It runs
-through ``repro.api.build_runtime`` like any user, then checks that
+fused root merge, all on the host CPU; the mesh pipeline with 8-tick
+persistent scans; 16 instances at most, scaled from 8 to 16 mid-stream.
+It runs through ``repro.api.build_runtime`` like any user, then checks
+that
 
 * every window the run closed equals a plain numpy windowed count of the
   same generated events (independent of ``repro.core``), with no stash,
   output buffer or window ring overflowing anywhere;
 * at least one reconfiguration was injected mid-stream and switched;
 * one chip: the compiled persistent step calls the ``segment_aggregate``
-  and ``scalegate_merge`` kernels, and the root merge the
-  ``scalegate_merge_stacked`` kernel, as TPU custom calls;
+  and ``scalegate_merge`` kernels as TPU custom calls, and the ingest
+  tier's gates (root and leaves) live on the host's CPU device;
 * ``--four-chips``: sigma's key blocks sit on 4 distinct devices and the
   compiled step moves 0 bytes between them.  This option runs that path
   and its reference only.
@@ -299,13 +300,17 @@ def main(argv=None) -> int:
         print(f"kernels in the compiled persistent step: {kernels}")
         check({"segment_aggregate", "scalegate_merge"} <= set(kernels),
               f"kernels missing from the persistent step: {kernels}")
-        root = res["rt"].tier.root
-        root_kernels = custom_calls(root.stacked_hlo())
-        print(f"root merge: device={root.device}, {root.rounds} rounds, "
-              f"kernels {root_kernels}")
-        check(root.device and root.rounds > 0
-              and "scalegate_merge_stacked" in root_kernels,
-              "the root merge did not run the stacked kernel")
+        tier = res["rt"].tier
+        gates = [tier.root.state] + [h.gate.state
+                                     for h in tier._handles.values()]
+        platforms = sorted({d.platform for g in gates
+                            for a in jax.tree.leaves(g) for d in a.devices()})
+        print(f"ingest gates: root (fused={tier.root.device}, "
+              f"{tier.root.rounds} rounds) and {len(gates) - 1} leaves, "
+              f"on {platforms}")
+        check(tier.root.rounds > 0 and len(gates) > 1
+              and platforms == ["cpu"],
+              "the ingest tier's gates are not on the host CPU")
 
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,

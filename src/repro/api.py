@@ -175,7 +175,7 @@ def make_tier(cfg: RuntimeConfig, source, *, record: bool = False,
         source, cfg.n_sources, cfg.ingest_hosts, worker=cfg.ingest_worker,
         leaf_cap=cfg.leaf_cap, root_cap=cfg.root_cap,
         chan_cap=cfg.chan_cap, max_leaves=cfg.effective_max_leaves,
-        backend=cfg.backend, record=record,
+        record=record,
         schedule=getattr(source, "schedule", None), out_pad=cfg.out_pad,
         root_device=cfg.root_device, snapshot_every=cfg.checkpoint_every,
         restore=restore)

@@ -14,7 +14,10 @@ host round-trip per tick.  This runtime makes the stream *live*:
   outputs (sinks keep device handles).  The only host syncs are the
   sampled metrics of the *previous* tick — the ``switched`` flag and the
   per-instance load vector — fetched while the current tick computes
-  (double buffering);
+  (double buffering).  With a controller in the loop they are fetched
+  before the controller is asked instead: it then decides on the freshest
+  metrics, and its decision rides a dispatch with no device work queued
+  ahead of it, rather than waiting out the whole previous one;
 * the **control loop** closes §8.4-§8.5: each tick, a ``MetricsBus``
   snapshot (offered/measured rate, per-instance load, queue depth) is fed
   to the controller, and an emitted ``Reconfiguration`` is injected
@@ -263,8 +266,9 @@ class AsyncStreamRuntime:
                       frontier: np.ndarray):
         """Group up to ``super_batch`` consecutive same-shape ticks and
         stage each group as one device stack.  A shape change flushes the
-        open group early; a partial group is padded with all-invalid no-op
-        ticks so every dispatch reuses ONE compiled K-tick executable."""
+        open group early; ``stage_super`` pads a partial group with
+        all-invalid no-op ticks so every dispatch reuses ONE compiled
+        K-tick executable."""
         K = self.super_batch
         group: List[T.TupleBatch] = []
         metas: List[TickMeta] = []
@@ -275,12 +279,9 @@ class AsyncStreamRuntime:
             if not group:
                 return
             n_pad = K - len(group)
-            b0 = group[0]
             tick_id = metas[0].tick_id
             with _obs.span("ingest.stage", tick=tick_id):
-                ticks = group + [T.empty_batch(b0.batch, b0.kmax,
-                                               b0.payload_width)] * n_pad
-                stack = self.pipeline.stage_super(ticks)   # async transfer
+                stack = self.pipeline.stage_super(group, K)  # async transfer
             with _obs.span("ingest.blocked", tick=tick_id):
                 self.queue.put(StagedSuper(metas=metas, stack=stack,
                                            n_pad=n_pad))
@@ -407,6 +408,7 @@ class AsyncStreamRuntime:
         self.metrics.start()
         th.start()
         pending = None
+        t_drained = 0.0          # when the latest drain returned
         try:
             while True:
                 t_wait = time.perf_counter()
@@ -428,6 +430,12 @@ class AsyncStreamRuntime:
                     with _obs.span("runtime.checkpoint"):
                         self.checkpointer.maybe_save(meta.tick_id,
                                                      meta.frontier_before)
+                follows = pending is not None
+                if follows and self.controller is not None:
+                    # decide on the previous tick's metrics, once the
+                    # device has nothing queued ahead of this dispatch
+                    self._drain(pending, idle_s=idle_s)
+                    pending, t_drained = None, time.perf_counter()
                 rc, t_decide = self._decide(meta)
                 ids = {"tick": meta.tick_id}
                 if rc is not None:
@@ -462,11 +470,12 @@ class AsyncStreamRuntime:
                     # tick T-1 syncs while T computes; the wait for T's
                     # arrival was source idle time, not T-1's latency
                     self._drain(pending, idle_s=idle_s)
-                    if rc is not None:
-                        # the device work queued ahead of this dispatch
-                        _obs.interval("reconfig.behind", t_decide,
-                                      time.perf_counter(),
-                                      epoch=int(rc.epoch))
+                    t_drained = time.perf_counter()
+                if follows and rc is not None:
+                    # the device work queued ahead of this dispatch
+                    _obs.interval("reconfig.behind", t_decide,
+                                  max(t_decide, t_drained),
+                                  epoch=int(rc.epoch))
                 pending = (meta.tick_id, switched, inst_load, meta, t0)
             if pending is not None:
                 self._drain(pending)

@@ -85,13 +85,20 @@ def inject_ctrl(inc_stack: T.TupleBatch, ctrl: T.TupleBatch, rc_tick,
     return jax.tree.map(upd, inc_stack, ctrl)
 
 
-@jax.jit
-def _pad_stack(pad: T.TupleBatch, *batches: T.TupleBatch) -> T.TupleBatch:
-    """Append the all-invalid ctrl pad to each of K same-shape ticks and
-    stack them into one [K, B] super-batch in ONE compiled call — staging
-    must stay far cheaper than a tick, and the host-side alternative
-    (K x n_fields separate concat/stack dispatches) is not."""
-    padded = [T.concat(b, pad) for b in batches]
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _pad_stack(n_inputs: int, k: int, *batches: T.TupleBatch) -> T.TupleBatch:
+    """Append the all-invalid ctrl pad (``n_inputs`` lanes) to each of the
+    same-shape ticks, follow them with all-invalid no-op ticks up to ``k``,
+    and stack all into one [k, B] super-batch in ONE compiled call —
+    staging must stay far cheaper than a tick, and the alternative (k x
+    n_fields separate concat/stack dispatches) is not.  The pad and the
+    no-op ticks are made inside the program, so it runs where the ticks
+    are (the ingest tier's host CPU) and reads nothing from elsewhere."""
+    b0 = batches[0]
+    pad = T.empty_batch(n_inputs, b0.kmax, b0.payload_width)
+    noop = T.empty_batch(b0.batch, b0.kmax, b0.payload_width)
+    ticks = list(batches) + [noop] * (k - len(batches))
+    padded = [T.concat(b, pad) for b in ticks]
     return jax.tree.map(lambda *xs: jnp.stack(xs), *padded)
 
 
@@ -229,7 +236,7 @@ class VSNPipeline:
         """Asynchronously place a tick on the device (async ingest: the
         ``device_put`` of tick T+1 overlaps device compute of tick T)."""
         self._ensure_gate(incoming)
-        return jax.device_put(incoming)
+        return jax.device_put(incoming, jax.devices()[0])
 
     def step_staged(self, staged: T.TupleBatch,
                     reconfig: Optional[Reconfiguration] = None,
@@ -282,17 +289,17 @@ class VSNPipeline:
             fold_frontier(frontier, b, self.op.n_inputs)
         return frontier
 
-    def stage_super(self, batches) -> T.TupleBatch:
-        """Stack K same-shape ticks — each with its all-invalid ctrl pad
-        region appended — into one [K, B] device-resident super-batch (one
+    def stage_super(self, batches, k: Optional[int] = None) -> T.TupleBatch:
+        """Stack the same-shape ticks — each with its all-invalid ctrl pad
+        region appended, no-op ticks after them up to ``k`` (default: as
+        many as given) — into one [k, B] device-resident super-batch (one
         transfer for the whole scan; ``inject_ctrl`` later rewrites the pad
         of at most one tick)."""
         batches = list(batches)
         assert batches, "empty super-batch"
         self._ensure_gate(batches[0])
-        kmax, p = batches[0].kmax, batches[0].payload_width
-        pad = T.empty_batch(self.op.n_inputs, kmax, p)
-        return _pad_stack(pad, *batches)
+        stack = _pad_stack(self.op.n_inputs, k or len(batches), *batches)
+        return jax.device_put(stack, jax.devices()[0])
 
     def run_persistent_staged(self, stack: T.TupleBatch,
                               reconfig: Optional[Reconfiguration] = None,
@@ -710,17 +717,16 @@ class MeshPipeline:
         return outs1, outs2, switched[0]
 
     # -- persistent K-tick driver ------------------------------------------
-    def stage_super(self, batches) -> T.TupleBatch:
-        """Stack K ticks (each with its all-invalid ctrl pad region) and
-        replicate the [K, B] super-batch across the mesh in one transfer."""
+    def stage_super(self, batches, k: Optional[int] = None) -> T.TupleBatch:
+        """Stack the ticks (each with its all-invalid ctrl pad region, no-op
+        ticks after them up to ``k``) and replicate the [k, B] super-batch
+        across the mesh in one transfer."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         batches = list(batches)
         assert batches, "empty super-batch"
         self._ensure_gate(batches[0])
-        kmax, p = batches[0].kmax, batches[0].payload_width
-        pad = T.empty_batch(self.op.n_inputs, kmax, p)
-        stack = _pad_stack(pad, *batches)
+        stack = _pad_stack(self.op.n_inputs, k or len(batches), *batches)
         rep = NamedSharding(self.mesh, P())
         return jax.tree.map(lambda a: jax.device_put(a, rep), stack)
 

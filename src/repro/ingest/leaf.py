@@ -4,7 +4,8 @@ A leaf is the paper's per-host ScaleGate (§6 hierarchical TB): it merges
 the timestamp-sorted streams of its *disjoint* source subset into a ready
 stream that is itself timestamp-sorted — so the leaf outputs compose as
 sources of the root merge one level up.  The leaf is a thin, host-driven
-wrapper around the same ``scalegate.push`` the pipelines use:
+wrapper around the same ``scalegate.push`` the pipelines use, run where
+the gate lives (``host_device``: the host's CPU, off the chip's queue):
 
 * per round it pushes its routed slice (chunked to a fixed lane width so
   jit shapes stay static) and emits a ``LeafOut`` — the *compacted* ready
@@ -24,8 +25,10 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import warnings
 from typing import Dict, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro import obs as _obs
@@ -41,8 +44,7 @@ def batch_to_np(b: T.TupleBatch) -> Dict[str, np.ndarray]:
 
 
 def np_to_batch(d: Dict[str, np.ndarray]) -> T.TupleBatch:
-    import jax.numpy as jnp
-    return T.TupleBatch(**{f: jnp.asarray(d[f]) for f in FIELDS})
+    return T.TupleBatch(**to_host({f: d[f] for f in FIELDS}))
 
 
 def compact_np(d: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
@@ -87,10 +89,43 @@ def pad_np(d: Dict[str, np.ndarray], n: int) -> Dict[str, np.ndarray]:
 
 
 @functools.lru_cache(maxsize=None)
+def host_device():
+    """The device the tier's gates live and merge on: the host's CPU, so
+    that a merge and its read-back never queue behind the step on the
+    accelerator.  None in a process with no CPU backend (a
+    ``JAX_PLATFORMS`` naming only the accelerator): the gates then keep
+    the default device, as the one warning says."""
+    try:
+        return jax.devices("cpu")[0]
+    except RuntimeError:
+        warnings.warn("no CPU backend in this process: the ingest tier's "
+                      "ScaleGate merges run on the default device",
+                      RuntimeWarning, stacklevel=2)
+        return None
+
+
+def gate_backend() -> Optional[str]:
+    """The merge's kernel backend, the one the gates' device takes:
+    ``xla`` on the host CPU, the dispatcher's default elsewhere."""
+    return "xla" if host_device() is not None else None
+
+
+def to_host(tree):
+    """Commit ``tree`` (numpy or jax arrays) to the gates' device; jitted
+    pushes over committed arguments run there."""
+    return jax.device_put(tree, host_device())
+
+
+def on_host():
+    """Context in which this thread's new jax arrays are made on the gates'
+    device (membership masks, gammas, fresh or restored gate state)."""
+    return jax.default_device(host_device())
+
+
+@functools.lru_cache(maxsize=None)
 def _jit_push(backend: Optional[str]):
     """One jitted ``scalegate.push`` per backend, shared by every gate (the
     jit cache then dedups compilations across leaves by shape)."""
-    import jax
     return jax.jit(functools.partial(scalegate.push, backend=backend))
 
 
@@ -131,26 +166,26 @@ class LeafGate:
 
     def __init__(self, leaf_id: int, n_sources: int, owned: np.ndarray,
                  cap: int, kmax: int, payload_width: int,
-                 backend: Optional[str] = None, chunk: Optional[int] = None,
-                 state: Optional[Dict] = None):
-        import jax.numpy as jnp
+                 chunk: Optional[int] = None, state: Optional[Dict] = None):
         self.leaf_id = leaf_id
         self.n_sources = n_sources
         self.kmax = kmax
         self.payload_width = payload_width
-        self.backend = backend
+        self.backend = gate_backend()
         # chunk width: combined merge size is cap + chunk; keeping it a
         # power of two lets merge_order take the bitonic-kernel path
         self.chunk = chunk or cap
-        if state is not None:
-            # restore: stash / frontier / active mask all come from the
-            # snapshot (the owned mask is part of the exported state)
-            self.state = scalegate.import_np(state)
-        else:
-            self.state = scalegate.init_scalegate(
-                n_sources, cap, kmax, payload_width,
-                active=jnp.asarray(owned, bool))
-        self._push = _jit_push(backend)
+        with on_host():
+            if state is not None:
+                # restore: stash / frontier / active mask all come from the
+                # snapshot (the owned mask is part of the exported state)
+                st = scalegate.import_np(state)
+            else:
+                st = scalegate.init_scalegate(
+                    n_sources, cap, kmax, payload_width,
+                    active=np.asarray(owned, bool))
+            self.state = to_host(st)
+        self._push = _jit_push(self.backend)
 
     def export_state(self) -> Dict:
         """Picklable numpy snapshot of the gate (stash + frontier +
@@ -162,7 +197,8 @@ class LeafGate:
                    final: bool = False) -> LeafOut:
         """Push this round's routed tuples (possibly none) and report.
         Every chunk is pushed before any result is read back, so the
-        round's one wait for the device is the span ``leaf.fetch``."""
+        round's one wait for its merges is the span ``leaf.fetch`` (on the
+        host CPU, a copy once the merge is done)."""
         outs = []
         lanes = 0 if slice_np is None else slice_np["tau"].shape[0]
         off = 0
@@ -190,21 +226,21 @@ class LeafGate:
 
     # -- ESG membership ------------------------------------------------------
     def _mask(self, src: int):
-        import jax.numpy as jnp
         m = np.zeros((self.n_sources,), bool)
         m[src] = True
-        return jnp.asarray(m)
+        return to_host(m)
 
     def add_source(self, src: int, gamma: int) -> None:
-        self.state = scalegate.add_sources(self.state, self._mask(src), gamma)
+        with on_host():
+            self.state = scalegate.add_sources(self.state, self._mask(src),
+                                               gamma)
 
     def remove_source(self, src: int) -> None:
         self.state = scalegate.remove_sources(self.state, self._mask(src))
 
     def flush_all(self) -> None:
-        import jax.numpy as jnp
         self.state = scalegate.remove_sources(
-            self.state, jnp.ones((self.n_sources,), bool))
+            self.state, to_host(np.ones((self.n_sources,), bool)))
 
     def apply(self, ops: Sequence[Tuple]) -> bool:
         """Apply a reconfiguration op list; returns True when this leaf is

@@ -28,6 +28,12 @@ either ``merge_order`` backend contract (``(tau, source, arrival)`` on xla,
 ``(tau, arrival)`` on the Pallas bitonic path) — the root's ready *set*
 and tau grouping are identical regardless (see
 ``repro.core.scalegate.TIE_BREAK``).
+
+Placement: like the leaves, the root keeps its gate on the host's CPU
+(``leaf.host_device``) and merges there with the backend that device takes,
+so neither a round nor the read-back of its output queues behind the step
+on the accelerator; the emitted batch is committed to the host and crosses
+to the accelerator once per dispatch, in the pipeline's ``stage_super``.
 """
 
 from __future__ import annotations
@@ -42,6 +48,7 @@ from repro import obs as _obs
 from repro.core import scalegate
 from repro.core import tuples as T
 from repro.core import watermark as wm
+from repro.ingest import leaf as L
 from repro.ingest.leaf import (FIELDS, LeafOut, concat_np, empty_np,
                                np_to_batch, pad_np)
 
@@ -77,23 +84,22 @@ def _jit_push_stacked(backend: Optional[str]):
 
 
 class RootMerge:
-    """``device=True`` selects the fused on-device round: per-leaf ready
-    chunks are stacked into one rank-2 buffer and merged by a single
-    ``scalegate_merge_stacked`` kernel call with the watermark gate
-    evaluated on device (``wm.fold_reports``), so the steady-state round
-    issues no blocking host readback.  The per-round invariant checks of
-    the host path then run every ``check_every`` rounds instead (each
-    check is a device sync); stats accrue lazily (``sync_stats``)."""
+    """``device=True`` selects the fused round: per-leaf ready chunks are
+    stacked into one rank-2 buffer and merged by a single
+    ``scalegate_merge_stacked`` call with the watermark gate evaluated in
+    the same program (``wm.fold_reports``), so the steady-state round
+    issues no blocking readback.  The per-round invariant checks of the
+    per-round path then run every ``check_every`` rounds instead (each
+    check is a sync); stats accrue lazily (``sync_stats``)."""
 
     def __init__(self, max_leaves: int, cap: int, kmax: int,
                  payload_width: int, active_leaves: Sequence[int],
-                 backend: Optional[str] = None, out_pad: int = MIN_PAD,
-                 device: bool = False, check_every: int = 8):
-        import jax.numpy as jnp
+                 out_pad: int = MIN_PAD, device: bool = False,
+                 check_every: int = 8):
         self.max_leaves = max_leaves
         self.kmax = kmax
         self.payload_width = payload_width
-        self.backend = backend
+        self.backend = L.gate_backend()
         # lane floor for the incoming pad: a floor near the steady-state
         # round volume keeps the emitted batch shape constant, so the
         # downstream pipeline compiles one step instead of one per bucket
@@ -107,19 +113,19 @@ class RootMerge:
             cap = ((cap + self.chunk - 1) // self.chunk) * self.chunk
         active = np.zeros((max_leaves,), bool)
         active[list(active_leaves)] = True
-        self.state = scalegate.init_scalegate(
-            max_leaves, cap, kmax, payload_width, active=jnp.asarray(active))
-        self._push = _jit_push_wstate(backend)
-        self._push_stacked = _jit_push_stacked(backend)
+        with L.on_host():
+            self.state = L.to_host(scalegate.init_scalegate(
+                max_leaves, cap, kmax, payload_width, active=active))
+        self._push = _jit_push_wstate(self.backend)
+        self._push_stacked = _jit_push_stacked(self.backend)
         # -- invariants + accounting -------------------------------------
         self.last_emitted_tau = -1       # total-order witness across rounds
         self.wmark = -1                  # monotone watermark witness
         self.leaf_overflow: Dict[int, int] = {l: 0 for l in active_leaves}
         self.tuples_out = 0
         self.rounds = 0
-        self._out_valid: List = []       # device count handles, unsynced
+        self._out_valid: List = []       # count handles, unsynced
         self._last_overflow_warned = 0
-        self._stacked_structs = None     # abstract args of the last round
 
     @property
     def overflow(self) -> int:
@@ -127,14 +133,14 @@ class RootMerge:
 
     # -- membership ----------------------------------------------------------
     def _mask(self, leaf: int):
-        import jax.numpy as jnp
         m = np.zeros((self.max_leaves,), bool)
         m[leaf] = True
-        return jnp.asarray(m)
+        return L.to_host(m)
 
     def add_leaf(self, leaf: int, gamma: int) -> None:
-        self.state = scalegate.add_sources(self.state, self._mask(leaf),
-                                           gamma)
+        with L.on_host():
+            self.state = scalegate.add_sources(self.state, self._mask(leaf),
+                                               gamma)
         self.leaf_overflow.setdefault(leaf, 0)
 
     def remove_leaf(self, leaf: int) -> None:
@@ -142,10 +148,11 @@ class RootMerge:
 
     def clamp_leaf(self, leaf: int, gamma: int) -> None:
         """The leaf gained a migrated source with safe bound gamma."""
+        with L.on_host():
+            wmark = wm.clamp_frontier(self.state.wmark, self._mask(leaf),
+                                      gamma)
         self.state = scalegate.ScaleGateState(
-            stash=self.state.stash,
-            wmark=wm.clamp_frontier(self.state.wmark, self._mask(leaf),
-                                    gamma),
+            stash=self.state.stash, wmark=wmark,
             overflow=self.state.overflow)
 
     def apply_pre(self, root_ops: Sequence) -> None:
@@ -189,8 +196,6 @@ class RootMerge:
         return self._push_host(outs)
 
     def _push_host(self, outs: Sequence[LeafOut]) -> T.TupleBatch:
-        import jax.numpy as jnp
-
         reports, rmask = self._fold_leaf_reports(outs)
         incoming_np = concat_np([o.ready for o in outs],
                                 self.kmax, self.payload_width)
@@ -198,8 +203,8 @@ class RootMerge:
         incoming = np_to_batch(pad_np(incoming_np, bucket(n, self.out_pad)))
 
         wstate = wm.observe_explicit(self.state.wmark,
-                                     jnp.asarray(reports, jnp.int32),
-                                     jnp.asarray(rmask))
+                                     *L.to_host((reports.astype(np.int32),
+                                               rmask)))
         prev_overflow = self.overflow
         self.state, out = self._push(self.state, incoming, wstate)
 
@@ -239,14 +244,11 @@ class RootMerge:
 
     def _push_device(self, outs: Sequence[LeafOut]) -> T.TupleBatch:
         """The fused round: stack per-leaf ready chunks into rank-2 rows and
-        issue ONE ``push_stacked`` (merge + device-side watermark gate) —
-        no blocking host sync in the steady state.  Arrival order inside
-        the stacked buffer preserves the leaves' relative lane order, so
-        the emitted (tau, arrival) stream groups exactly like the host
+        issue ONE ``push_stacked`` (merge + in-program watermark gate) —
+        no blocking sync in the steady state.  Arrival order inside the
+        stacked buffer preserves the leaves' relative lane order, so the
+        emitted (tau, arrival) stream groups exactly like the per-round
         path's compacted concat."""
-        import jax
-        import jax.numpy as jnp
-
         reports, rmask = self._fold_leaf_reports(outs)
         chunk = self.chunk
         rows = []
@@ -265,14 +267,11 @@ class RootMerge:
         if len(rows) < n_rows:
             empty = pad_np(empty_np(self.kmax, self.payload_width), chunk)
             rows += [empty] * (n_rows - len(rows))
-        stacked = T.TupleBatch(
-            **{f: jnp.asarray(np.stack([r[f] for r in rows]))
-               for f in FIELDS})
-        args = (self.state, stacked, jnp.asarray(reports, jnp.int32),
-                jnp.asarray(rmask))
-        self._stacked_structs = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)
-        self.state, out = self._push_stacked(*args)
+        stacked = np_to_batch({f: np.stack([r[f] for r in rows])
+                               for f in FIELDS})
+        self.state, out = self._push_stacked(
+            self.state, stacked,
+            *L.to_host((reports.astype(np.int32), rmask)))
         self.rounds += 1
         _obs.counter_inc("root.rounds")
         self._out_valid.append(out.num_valid())
@@ -280,16 +279,9 @@ class RootMerge:
             self._verify_round(out)
         return out
 
-    def stacked_hlo(self) -> str:
-        """Compiled HLO of the fused round at the last shape pushed — the
-        ``scalegate_merge_stacked`` kernel shows in it as a TPU custom
-        call on the chip."""
-        return self._push_stacked.lower(
-            *self._stacked_structs).compile().as_text()
-
     def _verify_round(self, out: T.TupleBatch) -> None:
-        """The host-path invariant checks, run periodically on the device
-        path (each is a device sync).  ``last_emitted_tau`` then witnesses
+        """The per-round path's invariant checks, run periodically on the
+        fused path (each is a sync).  ``last_emitted_tau`` then witnesses
         order across *checked* rounds — still sound, since a correct
         emitted stream is non-decreasing across every round between them."""
         w = int(self.state.wmark.value())
@@ -319,7 +311,7 @@ class RootMerge:
         _obs.gauge_set("root.wmark", self.wmark)
 
     def sync_stats(self) -> None:
-        """Materialize the device path's lazily-tracked stats (blocks on the
+        """Materialize the fused path's lazily-tracked stats (blocks on the
         accumulated count handles; call outside the hot loop)."""
         if self._out_valid:
             self.tuples_out += int(np.sum([int(np.asarray(v))
@@ -360,7 +352,8 @@ class RootMerge:
         got = np.asarray(snap["sg"]["stash"]["tau"]).shape[0]
         want = self.state.capacity
         assert got == want, f"root stash capacity changed: {got} != {want}"
-        self.state = scalegate.import_np(snap["sg"])
+        with L.on_host():
+            self.state = L.to_host(scalegate.import_np(snap["sg"]))
         meta = snap["meta"]
         self.last_emitted_tau = int(meta["last_emitted_tau"])
         self.wmark = int(meta["wmark"])

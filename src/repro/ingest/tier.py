@@ -21,6 +21,12 @@ Topology (paper §6's elastic/hierarchical TB)::
   source upstream of ``pipeline.stage()``) and yields one totally-ordered
   ready batch per round.
 
+Every gate of the tier, leaf and root, lives and merges on the host's CPU
+device (``leaf.host_device``), whatever the worker mode: ingest is host
+work, and a merge on the accelerator would queue, with its read-back,
+behind the step.  The gauge ``ingest.gates_on_host`` (1 or 0, set when
+the tier starts) says which placement ran.
+
 Backpressure propagates root→leaf→source through the bounded channels
 alone: a slow consumer stops collecting rounds, the leaf→root channel
 fills, leaves block, the router's leaf channels fill, and the source
@@ -130,7 +136,7 @@ class _Handle:
 
     def __init__(self, leaf_id: int):
         self.leaf_id = leaf_id
-        self.gate: Optional[L.LeafGate] = None    # inline only
+        self.gate: Optional[L.LeafGate] = None    # inline/thread only
         self.chan = None                          # thread/process only
         self.thread: Optional[threading.Thread] = None
         self.proc = None
@@ -147,8 +153,7 @@ class IngestTier:
     def __init__(self, stream, n_sources: int, n_leaves: int, *,
                  worker: str = "thread", leaf_cap: int = 128,
                  root_cap: int = 256, chan_cap: int = 4,
-                 max_leaves: Optional[int] = None,
-                 backend: Optional[str] = None, record: bool = False,
+                 max_leaves: Optional[int] = None, record: bool = False,
                  schedule=None, out_pad: int = MIN_PAD,
                  root_device: bool = False, root_check_every: int = 8,
                  snapshot_every: int = 0, restore: Optional[Dict] = None):
@@ -160,7 +165,6 @@ class IngestTier:
         self.leaf_cap = leaf_cap
         self.root_cap = root_cap
         self.chan_cap = chan_cap
-        self.backend = backend
         self.max_leaves = max_leaves or max(2 * n_leaves, n_leaves + 4)
         assert n_leaves <= self.max_leaves
         self.schedule = schedule
@@ -280,10 +284,11 @@ class IngestTier:
             st = next(iter(self._restore["leaf_states"].values()))
             want_kmax = st["stash"]["keys"].shape[1]
             assert want_kmax == self._kmax, (want_kmax, self._kmax)
+        _obs.gauge_set("ingest.gates_on_host",
+                       int(L.host_device() is not None))
         self.root = RootMerge(self.max_leaves, self.root_cap, self._kmax,
                               self._pw, self.part.leaves,
-                              backend=self.backend, out_pad=self.out_pad,
-                              device=self.root_device,
+                              out_pad=self.out_pad, device=self.root_device,
                               check_every=self.root_check_every)
         if self._restore is not None:
             self.root.import_state(self._restore["root"])
@@ -304,22 +309,19 @@ class IngestTier:
     def _spawn(self, leaf_id: int, owned: np.ndarray,
                state: Optional[Dict] = None) -> None:
         h = _Handle(leaf_id)
-        if self.worker == "inline":
+        if self.worker != "process":
             h.gate = L.LeafGate(leaf_id, self.n_sources, owned,
                                 self.leaf_cap, self._kmax, self._pw,
-                                backend=self.backend, state=state)
-        elif self.worker == "thread":
-            gate = L.LeafGate(leaf_id, self.n_sources, owned, self.leaf_cap,
-                              self._kmax, self._pw, backend=self.backend,
-                              state=state)
+                                state=state)
+        if self.worker == "thread":
             h.chan = make_channel("thread", self.chan_cap)
             h.thread = threading.Thread(
                 target=L.run_gate_loop,
-                args=(gate, h.chan.get, self._root_in.put), daemon=True)
+                args=(h.gate, h.chan.get, self._root_in.put), daemon=True)
             h.thread.start()
-        else:                                     # process
-            # no backend: a leaf process runs on the host CPU (xla), never
-            # on the chip the parent holds (repro.host_worker)
+        elif self.worker == "process":
+            # a leaf process runs on the host CPU (xla) as a whole, never
+            # touching the chip the parent holds (repro.host_worker)
             cfg = dict(leaf_id=leaf_id, n_sources=self.n_sources,
                        owned=np.asarray(owned, bool), cap=self.leaf_cap,
                        kmax=self._kmax, payload_width=self._pw,

@@ -27,8 +27,8 @@ detection→switch latency.
   stream (event times intact) via ``io.sources``;
 * ``--super-batch K``  stages K consecutive ticks as one device-resident
   stack and dispatches the persistent compiled K-tick scan;
-* ``--fused-root``     (with ``--ingest-hosts``) runs the root merge on
-  device (``RootMerge(device=True)``);
+* ``--fused-root``     (with ``--ingest-hosts``) runs the root merge as
+  one fused stacked-leaf call a round (``RootMerge(device=True)``);
 * ``--ingest-hosts N``  spreads the workload over N physical sources and
   merges them through the hierarchical multi-host ScaleGate upstream of
   the runtime; the tier's output set is asserted against the
@@ -188,8 +188,8 @@ def main(argv=None):
                     help="stage K consecutive ticks as one device stack "
                          "and run the persistent compiled K-tick scan")
     ap.add_argument("--fused-root", action="store_true",
-                    help="with --ingest-hosts: run the root merge on "
-                         "device (one fused stacked-leaf kernel per round)")
+                    help="with --ingest-hosts: run the root merge as one "
+                         "fused stacked-leaf call per round")
     ap.add_argument("--checkpoint-dir", default=None,
                     help="take epoch-consistent snapshots into this dir")
     ap.add_argument("--checkpoint-every", type=int, default=0,

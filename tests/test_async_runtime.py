@@ -240,6 +240,31 @@ def test_detection_to_switch_accounting():
     assert all(t >= 0 for t in rep.detect_to_switch_ticks)
 
 
+@pytest.mark.parametrize("super_batch", [1, 2])
+def test_controller_decides_on_the_previous_dispatch(super_batch):
+    """With a controller in the loop, the previous dispatch is drained
+    before the controller is asked: each decision sees the metrics of
+    every dispatch handed over before its own, and nothing is queued on
+    the device ahead of the dispatch it rides."""
+    batches = agg_stream(n_ticks=8)
+    rt = None
+    seen = []
+
+    class Watch:
+        def observe_live(self, snap):
+            seen.append(len(rt.metrics.records))
+            return None
+
+    rt = AsyncStreamRuntime(agg_pipe(), ReplaySource(batches),
+                            controller=Watch(), queue_cap=2,
+                            super_batch=super_batch)
+    rt.run()
+    dispatches = len(batches) // super_batch
+    # no decision before a rate signal: the first two dispatches are not
+    # asked (see AsyncStreamRuntime._decide)
+    assert seen == list(range(2, dispatches))
+
+
 # ------------------------------------------------------- backpressure -----
 
 def test_bounded_queue_backpressure_slow_consumer():

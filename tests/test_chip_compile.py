@@ -117,7 +117,9 @@ def _q1_segment_aggregate_shapes():
     pipe = make_pipeline(cfg)
     pipe.ensure_gate_for(kmax, width)
     ctrl = T.empty_batch(pipe.op.n_inputs, kmax, width)
-    stack = jax.eval_shape(_pad_stack, ctrl, *[tick] * cfg.super_batch)
+    stack = jax.eval_shape(
+        lambda *ticks: _pad_stack(pipe.op.n_inputs, cfg.super_batch, *ticks),
+        *[tick] * cfg.super_batch)
     step = jax.make_jaxpr(pipe._persistent_fn)(
         pipe.sg, pipe.epoch, pipe.sigma, stack, ctrl, jnp.zeros((), I32),
         pipe.epoch.fmu, pipe.epoch.active)

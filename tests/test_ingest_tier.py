@@ -139,9 +139,10 @@ def test_process_leaf_runs_on_host_cpu(monkeypatch):
     the parent's environment asks for: on a TPU machine the chip belongs
     to the parent process alone.  The children inherit
     ``REPRO_KERNEL_BACKEND=pallas`` (the compiled kernels, which cannot run
-    on a CPU); the parent's own root merge runs xla by argument.  The
-    leaves' ``leaf_start`` events, shipped back on the round stream, report
-    what they actually ran."""
+    on a CPU); the parent's own root merge runs xla because it lives on
+    the host CPU, whatever that variable says.  The leaves' ``leaf_start``
+    events, shipped back on the round stream, report what they actually
+    ran."""
     from repro import obs
     from repro.obs import ObsConfig
 
@@ -150,8 +151,7 @@ def test_process_leaf_runs_on_host_cpu(monkeypatch):
     o = obs.install(ObsConfig(enabled=True))
     try:
         batches = agg_stream(n_ticks=2)
-        tier = IngestTier(batches, N_SRC, 2, **tier_kw(worker="process",
-                                                       backend="xla"))
+        tier = IngestTier(batches, N_SRC, 2, **tier_kw(worker="process"))
         outs = list(tier)
     finally:
         obs.set_current(prev)
@@ -160,6 +160,85 @@ def test_process_leaf_runs_on_host_cpu(monkeypatch):
     assert len(starts) == 2
     assert {(e["platform"], e["backend"]) for e in starts} == {("cpu", "xla")}
     assert os.getpid() not in {e["pid"] for e in starts}
+
+
+# ------------------------------------------------------ placement ---------
+
+def _on_host_cpu(tree) -> bool:
+    """Every array of ``tree`` is committed to the host's CPU device."""
+    import jax
+    from repro.ingest import leaf as L
+    return all(a.committed and a.devices() == {L.host_device()}
+               for a in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("worker", ["inline", "thread"])
+def test_gates_and_stream_live_on_host_cpu(worker):
+    """Every gate of the tier keeps its state on the host's CPU device and
+    emits batches committed there (so a merge never queues behind the step
+    on an accelerator, and ``stage_super`` stacks on the host); the gauge
+    ``ingest.gates_on_host`` says so; and the merged stream still equals
+    the flat oracle round for round."""
+    from repro import obs
+    from repro.obs import ObsConfig
+
+    batches = agg_stream()
+    prev = obs.get()
+    o = obs.install(ObsConfig(enabled=True))
+    try:
+        tier = IngestTier(batches, N_SRC, 2,
+                          **tier_kw(worker=worker, root_device=True))
+        it = iter(tier)
+        outs = [next(it)]
+        gates = [h.gate for h in tier._handles.values()]
+        assert len(gates) == 2
+        assert all(_on_host_cpu(g.state) for g in gates)
+        assert _on_host_cpu(tier.root.state)
+        outs += list(it)
+    finally:
+        obs.set_current(prev)
+    assert o.registry.gauges["ingest.gates_on_host"].value == 1
+    assert all(_on_host_cpu(b) for b in outs)
+    assert_ordered(outs)
+    oracle = single_gate_stream(batches, N_SRC, cap=96)
+    assert len(outs) == len(oracle)
+    for got, want in zip(outs, oracle):
+        assert collect_tuples([got]) == collect_tuples([want])
+
+
+def test_gates_stay_on_default_device_without_a_cpu_backend(monkeypatch):
+    """A process whose JAX has no CPU backend keeps today's placement (the
+    default device, its backend) and says so once: a warning, and the gauge
+    ``ingest.gates_on_host`` reads 0.  The stream is unchanged."""
+    import jax
+    from repro import obs
+    from repro.ingest import leaf as L
+    from repro.obs import ObsConfig
+
+    real = jax.devices
+
+    def devices(backend=None):
+        if backend == "cpu":
+            raise RuntimeError("Unknown backend cpu")
+        return real(backend)
+
+    monkeypatch.setattr(jax, "devices", devices)
+    L.host_device.cache_clear()
+    batches = agg_stream(n_ticks=3)
+    prev = obs.get()
+    o = obs.install(ObsConfig(enabled=True))
+    try:
+        with pytest.warns(RuntimeWarning, match="no CPU backend"):
+            assert L.host_device() is None
+        assert L.gate_backend() is None
+        outs = list(IngestTier(batches, N_SRC, 2,
+                               **tier_kw(worker="inline")))
+    finally:
+        obs.set_current(prev)
+        L.host_device.cache_clear()
+    assert o.registry.gauges["ingest.gates_on_host"].value == 0
+    assert collect_tuples(outs) == collect_tuples(
+        single_gate_stream(batches, N_SRC, cap=96))
 
 
 # ------------------------------------------------- runtime integration ----
@@ -347,14 +426,18 @@ def test_push_ready_set_identical_across_backends():
     assert outs["xla"] == outs["pallas-interpret"]
 
 
-def test_root_merge_tolerates_either_leaf_tie_break():
+def test_root_merge_tolerates_either_leaf_tie_break(monkeypatch):
     """Leaves running different merge_order contracts feed the same root:
-    output sets identical, order valid in both tiers."""
+    output sets identical, order valid in both tiers.  The gates take the
+    backend of the device they live on (xla on the host CPU); the test
+    steers them to the Pallas contract through ``gate_backend``."""
+    from repro.ingest import leaf as L
+
     batches = agg_stream(n_ticks=4)
     results = {}
     for backend in ("xla", "pallas-interpret"):
-        tier = IngestTier(batches, N_SRC, 2,
-                          **tier_kw(worker="inline", backend=backend))
+        monkeypatch.setattr(L, "gate_backend", lambda b=backend: b)
+        tier = IngestTier(batches, N_SRC, 2, **tier_kw(worker="inline"))
         outs = list(tier)
         assert_ordered(outs)
         results[backend] = collect_tuples(outs)

@@ -231,3 +231,33 @@ def test_mesh_persistent_hlo_has_no_host_transfers():
     pipe = make_mesh(1)
     pipe.run_persistent(stream(n_ticks=4))
     assert host_transfer_ops(pipe.persistent_hlo()) == []
+
+
+@pytest.mark.parametrize("kind", ["vsn", "mesh"])
+def test_stage_super_from_host_batches(kind):
+    """The ingest tier hands over ticks committed to the host's CPU device;
+    ``stage_super`` stacks them there (ctrl pad and no-op ticks included)
+    and places the stack where the pipeline runs — the same stack, placed
+    the same way, as from ticks on the default device."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.ingest import leaf as L
+
+    pipe = make_vsn() if kind == "vsn" else make_mesh(1)
+    batches = stream(n_ticks=3)
+    k = 4                                   # one all-invalid no-op tick
+    want = pipe.stage_super(batches, k)
+    got = pipe.stage_super([L.to_host(b) for b in batches], k)
+    place = (NamedSharding(pipe.mesh, P()) if kind == "mesh"
+             else jax.sharding.SingleDeviceSharding(jax.devices()[0]))
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        assert g.shape[0] == k
+        assert g.sharding.is_equivalent_to(place, g.ndim)
+        assert w.sharding.is_equivalent_to(place, w.ndim)
+    valid = np.asarray(got.valid)
+    assert not valid[len(batches):].any()
+    assert not valid[:, -pipe.op.n_inputs:].any()   # the ctrl pad lanes
+    np.testing.assert_array_equal(
+        valid[:len(batches), :batches[0].batch],
+        np.stack([np.asarray(b.valid) for b in batches]))
